@@ -1,6 +1,8 @@
 """Benchmark harness — one module per paper table/figure + beyond-paper.
 
 Prints ``name,us_per_call,derived`` CSV rows. ``--quick`` trims sweep sizes.
+A suite that raises prints an ``<suite>/ERROR`` row, the remaining suites
+still run, and the process exits non-zero.
 Roofline numbers come from the dry-run artifacts (benchmarks/dryrun_results,
 summarized by benchmarks/roofline_table.py), not from wall-time here.
 """
@@ -78,7 +80,11 @@ def main() -> None:
             f"unknown suite(s) {sorted(unknown)}; available: "
             f"{','.join(suites)}"
         )
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in suites.items():
         if name not in only:
             continue
@@ -90,6 +96,7 @@ def main() -> None:
             rows = fn(**kwargs)
         except Exception as e:  # noqa: BLE001
             print(f"{name}/ERROR,0,{type(e).__name__}:{e}", flush=True)
+            failed.append(name)
             continue
         for r in rows:
             print(f"{r[0]},{r[1]:.1f},{r[2]}", flush=True)
@@ -97,6 +104,8 @@ def main() -> None:
             f"{name}/_suite_wall,{(time.monotonic() - t0) * 1e6:.0f},ok",
             flush=True,
         )
+    if failed:
+        sys.exit(f"suites raised: {','.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -64,10 +64,14 @@ _COMMITTED_BASELINE = {
 
 def _force_host_devices() -> None:
     """Shard the batched scan across host cores (one forced CPU device per
-    core). Only possible before the jax backend initializes, and only done
-    when this suite runs — never as an import side effect, so other
-    benchmark suites keep their default single-device topology."""
+    core). Only possible before the jax backend initializes, only on a run
+    held to the CPU (``JAX_PLATFORMS=cpu``; on an accelerator the sweep
+    runs on its devices), and only done when this suite runs — never as an
+    import side effect, so other benchmark suites keep their default
+    single-device topology."""
     if "XLA_FLAGS" in os.environ:
+        return
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] != "cpu":
         return
     try:
         from jax._src import xla_bridge
@@ -377,7 +381,8 @@ def run(quick: bool = False, algos=None):
             "in-flight worm pool) and shards the sweep axis across host "
             "devices via pmap, so the speedup scales with available cores "
             "while the Python baseline is inherently single-core; the "
-            "Pallas chunked-kernel backend targets TPU/GPU"
+            "Pallas chunked-kernel backend does not lower for TPU yet, so "
+            "the ref scan is the engine on every platform"
         ),
         "env": {
             "cpu_count": os.cpu_count(),

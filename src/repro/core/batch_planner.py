@@ -20,10 +20,11 @@ for field. Three things make that hold:
   members (the dual-path rule never passes a pending member early), so C_p
   reduces to a prefix scan over dense pairwise price matrices;
 * ``batch_support`` gates batching on *exactness*: every price must be a
-  dyadic rational (multiple of 1/256) small enough that float32 sums stay
-  exact, the cost model must price routes edge-additively, and the fabric
-  must be healthy (degraded topologies detour through BFS fallback hops
-  that break the chain decomposition — those always take the host path).
+  dyadic rational (multiple of 1/q, q a power of two <= 256) small enough
+  that float32 sums stay exact, the cost model must price routes
+  edge-additively, and the fabric must be healthy (degraded topologies
+  detour through BFS fallback hops that break the chain decomposition —
+  those always take the host path).
 
 Anything outside the gate — degraded fabrics, non-dyadic objectives
 (energy), unregistered algorithms/models, oversized fabrics — falls back to
@@ -72,11 +73,12 @@ DEFAULT_ARENA_SIZE = 65_536
 # dispatched device compute of chunk k+1.
 DISPATCH_CHUNK = 512
 
-# Exactness gate: prices must be multiples of 1/SCALE and bounded so that
-# any candidate-cost sum stays inside float32's exact-integer range (2^24
-# in units of 1/SCALE). 1/256 covers every shipped dyadic model (hops,
-# weighted with dyadic link weights, contention on power-of-two extents).
-_SCALE = 256.0
+# Exactness gate: prices must be multiples of 1/q for a power of two
+# q <= SCALE, and bounded so that any candidate-cost sum stays inside
+# float32's exact-integer range (2^24 in units of 1/q). 1/256 covers every
+# shipped dyadic model (hops, weighted with dyadic link weights, contention
+# on power-of-two extents); integral prices (hops) take q = 1.
+_SCALE = 256
 _EXACT_LIMIT = float(2**24)
 
 
@@ -170,14 +172,23 @@ def label_chain_matrices(topo: MeshGrid, cost_model=None):
     return _label_chain_matrices_cached(topo, get_cost_model(cost_model))
 
 
-def _dyadic_exact(*arrays) -> bool:
-    """True iff every value is a multiple of 1/_SCALE representable and
-    summable exactly in float32 (see the exactness gate in batch_support)."""
-    for a in arrays:
-        q = np.asarray(a, np.float64) * _SCALE
-        if not np.all(np.isfinite(q)) or np.any(q != np.round(q)):
-            return False
-    return True
+def _dyadic_grain(*arrays) -> int | None:
+    """The least power of two ``q <= _SCALE`` such that every value is a
+    multiple of ``1/q`` (``None`` if there is none): sums of such values
+    are exact in float32 while they stay below 2^24 / q (see the exactness
+    gate in batch_support)."""
+    vals = np.concatenate(
+        [np.asarray(a, np.float64).reshape(-1) for a in arrays]
+    )
+    if not np.all(np.isfinite(vals)):
+        return None
+    q = 1
+    while q <= _SCALE:
+        scaled = vals * q
+        if np.all(scaled == np.round(scaled)):
+            return q
+        q *= 2
+    return None
 
 
 def batch_support(topo: MeshGrid, algo="DPM", cost_model=None) -> _Support:
@@ -209,15 +220,18 @@ def batch_support(topo: MeshGrid, algo="DPM", cost_model=None) -> _Support:
     if int(dist.max(initial=0)) * BIG + topo.num_nodes >= 2**31:
         return _Support(False, "route distances overflow the int32 rep key")
     wh, wl = label_chain_matrices(topo, cm)
-    if not _dyadic_exact(w_uni, wh, wl, [overhead]):
+    q = _dyadic_grain(w_uni, wh, wl, [overhead])
+    if q is None:
         return _Support(
             False, f"cost model {cm.name!r} prices are not dyadic (f32-exact)"
         )
-    bound = _SCALE * (
-        4.0
-        * topo.num_nodes
-        * (max(w_uni.max(initial=0), wh.max(initial=0), wl.max(initial=0))
-           + overhead + 1.0)
+    # Largest value any candidate sum reaches: C_t is at most NN unicast
+    # prices plus overheads; a C_p chain is label-monotone, so at most
+    # NN - 1 links per side, each priced at most max(w_uni); the greedy
+    # merge adds the costs of disjoint singles (C_t over disjoint members)
+    # plus their source legs. 4 NN (max(w_uni) + overhead + 1) covers all.
+    bound = q * (
+        4.0 * topo.num_nodes * (w_uni.max(initial=0) + overhead + 1.0)
     )
     if bound >= _EXACT_LIMIT:
         return _Support(False, "cost magnitudes exceed the f32-exact range")
@@ -416,11 +430,16 @@ class BatchPlanner:
         # decode in order — chunk k's host decode overlaps chunk k+1's
         # device compute where cores allow, so the pipeline costs
         # ~max(device, decode) instead of their sum.
+        import jax
+
         chunks = [
             keys[i : i + DISPATCH_CHUNK]
             for i in range(0, len(keys), DISPATCH_CHUNK)
         ]
-        outs = [self._dispatch(ck) for ck in chunks]
+        # run eagerly even while a caller's jit traces (EP MoE builds its
+        # all-to-all schedule inside the jitted step): plans are host data
+        with jax.ensure_compile_time_eval():
+            outs = [self._dispatch(ck) for ck in chunks]
         self._dispatches += len(chunks)
         plans: list[MulticastPlan] = []
         for ck, out in zip(chunks, outs):

@@ -20,7 +20,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..models.config import ArchConfig, MoEConfig
@@ -57,11 +56,14 @@ def moe_apply_ep(
 ):
     """Expert-parallel MoE FFN.  x: (B, S, d) -> (y, aux_loss).
 
-    Tokens flatten to (T, d) and shard over ``(*data_axes, EP_AXIS)``;
-    each shard routes its tokens locally, packs one (E_loc, cap, d) chunk
-    per expert shard, and the chunks ride the DPM all-to-all schedule out
-    and back.  Falls back to the dense path when the mesh or shapes don't
-    divide (single EP rank, ragged experts or tokens).
+    Tokens shard over ``(*data_axes, EP_AXIS)`` along the batch axis, or
+    along the sequence axis when the shards do not tile the batch (a long
+    prefill of few sequences), so the output stays distributed.  Each
+    shard flattens and routes its tokens locally, packs one
+    (E_loc, cap, d) chunk per expert shard, and the chunks ride the DPM
+    all-to-all schedule out and back.  Falls back to the dense path when
+    the mesh or shapes don't divide (single EP rank, ragged experts, or
+    token shards that tile neither B nor S).
     """
     m: MoEConfig = cfg.moe
     B, S, d = x.shape
@@ -70,19 +72,26 @@ def moe_apply_ep(
     sizes = dict(mesh.shape)
     n_ep = sizes.get(EP_AXIS, 1)
     n_data = math.prod(sizes[a] for a in data_axes) if data_axes else 1
-    T = B * S
-    if n_ep <= 1 or m.n_experts % n_ep or T % (n_data * n_ep):
+    mesh_axes = (*data_axes, EP_AXIS)
+    n_tok = n_data * n_ep
+    if n_ep <= 1 or m.n_experts % n_ep:
+        return moe_apply_dense(p, x, cfg)
+    if B % n_tok == 0:
+        tok_spec = P(mesh_axes)
+    elif S % n_tok == 0:
+        tok_spec = P(None, mesh_axes)
+    else:
         return moe_apply_dense(p, x, cfg)
 
     e_loc = m.n_experts // n_ep
-    t_loc = T // (n_data * n_ep)
+    t_loc = B * S // n_tok
     cap = capacity(m, t_loc)
     sched = alltoall_schedule(n_ep, algo)
-    tok_spec = P((*data_axes, EP_AXIS))
-    mesh_axes = (*data_axes, EP_AXIS)
 
-    def local(p_l, xt):
-        # xt: (t_loc, d) local tokens; expert leaves of p_l: (e_loc, ...)
+    def local(p_l, x_l):
+        # x_l: (B or B/n_tok, S/n_tok or S, d) local tokens, t_loc in all;
+        # expert leaves of p_l: (e_loc, ...)
+        xt = x_l.reshape(t_loc, d)
         ids, w, aux = route(p_l, xt, m)
         slot, keep = dispatch_indices(ids, m, cap)
         xt_rep = jnp.repeat(xt, m.top_k, axis=0)
@@ -113,13 +122,12 @@ def moe_apply_ep(
             h = xt @ p_l["shared_wi"].astype(xt.dtype)
             g = xt @ p_l["shared_wg"].astype(xt.dtype)
             y = y + (jax.nn.silu(g) * h) @ p_l["shared_wo"].astype(xt.dtype)
-        return y, jax.lax.pmean(aux, mesh_axes)
+        return y.reshape(x_l.shape), jax.lax.pmean(aux, mesh_axes)
 
-    y, aux = shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(_param_specs(p), tok_spec),
         out_specs=(tok_spec, P()),
-        check_rep=False,
-    )(p, x.reshape(T, d))
-    return y.reshape(B, S, d), aux
+        check_vma=False,
+    )(p, x)

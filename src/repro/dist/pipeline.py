@@ -13,7 +13,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -64,10 +63,10 @@ def pipeline_apply(layer_fn, stage_params, x: jax.Array, mesh, axis: str = "pipe
         # only the last stage wrote non-zeros; psum replicates the result
         return jax.lax.psum(outs, axis)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, x)

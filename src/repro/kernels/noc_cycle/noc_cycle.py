@@ -18,8 +18,10 @@ kernel launches.
 The static router geometry (``node_ports`` and friends) and the compiled-
 traffic tables are explicit kernel operands (``pallas_call`` kernels may
 not capture array constants), so the whole runner stays vmap/pmap-able
-over the sweep batch axis. On CPU the kernel runs under ``interpret=True``
-(the validation path CI exercises); on TPU/GPU it compiles via Mosaic.
+over the sweep batch axis. The kernel runs under ``interpret=True`` (the
+validation path CI exercises). It does not compile for TPU yet: Mosaic
+refuses the gathers of ``cycle_core``, so ``resolve_backend`` never picks
+it and the ``ref`` scan is the on-chip engine.
 """
 from __future__ import annotations
 

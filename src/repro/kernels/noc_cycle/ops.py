@@ -4,17 +4,21 @@
 the simulation outputs (``dtime``, counters, released-children mask):
 
 * ``ref`` — one ``lax.scan`` of ``ref.cycle_core`` with the (L,)-sized
-  delivery scatter inline. The CPU default: XLA fuses the dense cycle well,
-  and per-cycle state stays registers/cache-resident inside the scan.
+  delivery scatter inline. The default on every platform, and the engine
+  that compiles for TPU: XLA fuses the dense cycle, and per-cycle state
+  stays on chip inside the scan.
 * ``pallas`` / ``pallas_interpret`` — chunks of ``chunk`` cycles per fused
   kernel launch (``noc_cycle.make_chunk_runner``); state planes round-trip
   HBM only at chunk boundaries, and the packed arrival-event logs are
   decoded into ``dtime`` between launches. ``pallas_interpret`` is the
   CPU-validation flavor (bit-identical to ``ref`` — CI enforces it).
+  Compiled ``pallas`` does not lower for TPU yet: Mosaic refuses the
+  gathers of ``cycle_core`` (the first is ``take_along_axis`` in
+  ``ref.py``).
 
 Backend names resolve through ``kernels.noc_step.ops.resolve_backend``
-(``None``/``"auto"`` picks ``ref`` on CPU, ``pallas`` on TPU/GPU), so the
-whole xsim stack shares one switch.
+(``None``/``"auto"`` picks ``ref`` everywhere), so the whole xsim stack
+shares one switch.
 """
 from __future__ import annotations
 

@@ -4,8 +4,8 @@
 mask of one arbitration round: per resource, the admissible candidate with
 the smallest age key wins (keys are unique, so at most one winner per
 resource). The segmented-min reduction runs either through the Pallas kernel
-(``noc_step.py`` — TPU, or interpret mode for validation) or the jnp oracle
-(``ref.py`` — the default on CPU, where it lowers to a native scatter-min).
+(``noc_step.py``, interpret mode for validation) or the jnp oracle
+(``ref.py`` — the default, where it lowers to a native scatter-min).
 """
 from __future__ import annotations
 
@@ -16,14 +16,15 @@ from .noc_step import NOC_INF, segmented_min
 from .ref import segmented_min_ref
 
 
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
-
-
 def resolve_backend(backend: str | None) -> str:
-    """``None``/"auto" -> "ref" on CPU, "pallas" on TPU/GPU."""
+    """``None``/"auto" -> "ref" on every platform.
+
+    The fused ``noc_cycle`` Pallas kernel does not lower through Mosaic yet
+    (its gathers are refused), so the compiled ``lax.scan`` of the same
+    ``cycle_core`` is the engine on TPU as on CPU; ``"pallas"`` stays
+    callable explicitly."""
     if backend in (None, "auto"):
-        return "ref" if _on_cpu() else "pallas"
+        return "ref"
     if backend not in ("ref", "pallas", "pallas_interpret"):
         raise ValueError(f"unknown noc_step backend: {backend!r}")
     return backend

@@ -1,37 +1,34 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes, extract memory/cost/collective analyses, write JSON.
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch smollm-135m \
         --shape train_4k --mesh both
 
-The two env lines above MUST stay the first statements in this module: jax
-locks the device count on first init. Smoke tests / benches import other
-modules and keep their 1-device view.
+``main()`` forces 512 host devices before JAX's backend starts; importing
+this module leaves ``XLA_FLAGS`` and the device count alone.
 """
-import argparse  # noqa: E402
-import dataclasses  # noqa: E402
-import json  # noqa: E402
-import pathlib  # noqa: E402
-import time  # noqa: E402
-import traceback  # noqa: E402
+import argparse
+import json
+import os
+import pathlib
+import time
+import traceback
 
-import jax  # noqa: E402
+import jax
 
-from ..configs import ARCHS, SHAPES  # noqa: E402
-from ..models.model import decode_step, prefill  # noqa: E402
-from ..train.step import build_train_step  # noqa: E402
-from .hlo import analyze  # noqa: E402
-from .mesh import (  # noqa: E402
-    DCI_BW,
+from ..configs import ARCHS, SHAPES
+from ..models.model import decode_step, prefill
+from ..train.step import build_train_step
+from .hlo import analyze
+from .mesh import (
     HBM_BW,
     ICI_BW_PER_LINK,
     PEAK_FLOPS_BF16,
     make_production_mesh,
 )
-from .specs import build_cell, model_flops, param_counts  # noqa: E402
+from .specs import build_cell, model_flops, param_counts
+
+FORCED_DEVICES = 512
 
 RESULTS_DIR = pathlib.Path(__file__).resolve().parents[3] / "benchmarks" / "dryrun_results"
 
@@ -126,8 +123,6 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, variant: str = "baselin
     bytes_accessed = float(hlo["bytes"])
     coll = hlo["collectives"]
     xla_cost = compiled.cost_analysis() or {}
-    if isinstance(xla_cost, (list, tuple)):  # older jax returns [dict]
-        xla_cost = xla_cost[0] if xla_cost else {}
 
     n_chips = mesh.size
     mf = model_flops(cfg, shape, run)
@@ -169,6 +164,11 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, variant: str = "baselin
 
 
 def main():
+    # jax fixes the host device count when its backend starts, so this must
+    # run before the first device query below
+    os.environ["XLA_FLAGS"] = (
+        f"--xla_force_host_platform_device_count={FORCED_DEVICES}"
+    )
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

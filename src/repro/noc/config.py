@@ -51,10 +51,11 @@ class NoCConfig:
     # sim's Telemetry epochs and xsim's per-link utilization / per-router
     # conflict planes both bucket on cycle // epoch_len (DESIGN.md §10)
     epoch_len: int = 128
-    # xsim cycle-engine backend: None/"auto" picks "ref" on CPU and
-    # "pallas" (the fused chunk kernel) on TPU/GPU; "pallas_interpret"
-    # runs the kernel path on CPU for validation. An explicit ``backend=``
-    # argument to ``xsimulate`` overrides this.
+    # xsim cycle-engine backend: None/"auto" picks "ref" (the compiled
+    # lax.scan) on every platform, since the fused "pallas" chunk kernel
+    # does not lower for TPU yet; "pallas_interpret" runs the kernel path
+    # on CPU for validation. An explicit ``backend=`` argument to
+    # ``xsimulate`` overrides this.
     xsim_backend: str | None = None
 
     def make_topology(self):

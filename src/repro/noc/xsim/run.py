@@ -68,16 +68,26 @@ def _run_batch(stacked: dict, T: int, F: int, V: int, BD: int, L: int,
     return jax.vmap(fn)(stacked)
 
 
+def _devices_of(x: jax.Array) -> tuple[str, ...]:
+    return tuple(
+        f"{d.platform}:{d.id}"
+        for d in sorted(x.sharding.device_set, key=lambda d: d.id)
+    )
+
+
 def _run_sharded(stacked: dict, **kw):
-    """vmap the batch axis; additionally pmap-shard it across host devices
-    when more than one is available (e.g. CI/benchmarks force 2+ CPU devices
-    via --xla_force_host_platform_device_count) and it divides evenly."""
+    """vmap the batch axis; additionally pmap-shard it across the local
+    devices when more than one is available (the chips of a multi-chip
+    host, or CPU devices forced with --xla_force_host_platform_device_count)
+    and it divides evenly. Returns ``(out, devices)``: the devices that
+    hold the results."""
     B = stacked["link"].shape[0]
     D = jax.local_device_count()
     while D > 1 and B % D:
         D -= 1
     if D <= 1:
-        return _run_batch(stacked, **kw)
+        out = _run_batch(stacked, **kw)
+        return out, _devices_of(out["ctr"])
     fn = jax.pmap(
         jax.vmap(functools.partial(_run_one, **kw)), axis_name="shard"
     )
@@ -86,7 +96,11 @@ def _run_sharded(stacked: dict, **kw):
         for k, v in stacked.items()
     }
     out = fn(shaped)
-    return {k: jnp.reshape(v, (B,) + v.shape[2:]) for k, v in out.items()}
+    devices = _devices_of(out["ctr"])
+    return (
+        {k: jnp.reshape(v, (B,) + v.shape[2:]) for k, v in out.items()},
+        devices,
+    )
 
 
 @dataclass
@@ -113,6 +127,8 @@ class XSimResults:
     epoch_len: int = 0  # telemetry bucket width (cycles)
     lutil: np.ndarray | None = None  # (B, E, L) per-epoch link flits
     rconf: np.ndarray | None = None  # (B, E, NN) per-epoch router conflicts
+    backend: str = "ref"  # the cycle engine that ran (resolve_backend)
+    devices: tuple[str, ...] = ()  # "platform:id" of each device that ran it
 
     def _b(self, w: int, a: int) -> int:
         return w * len(self.algos) + a
@@ -295,7 +311,7 @@ def xsimulate(
     # ride the compiled ``flits`` table
     F = max(cfg.flits_per_packet, int(stacked["flits"].max()))
     stacked_j = {k: jnp.asarray(v) for k, v in stacked.items()}
-    out = _run_sharded(
+    out, devices = _run_sharded(
         stacked_j,
         T=T, F=F, V=cfg.vcs_per_class,
         BD=cfg.buffer_depth, L=ref.num_links, NN=ref.num_nodes, ND=ND,
@@ -328,6 +344,8 @@ def xsimulate(
         epoch_len=epoch_len,
         lutil=out["lutil"],
         rconf=out["rconf"],
+        backend=backend,
+        devices=devices,
     )
 
 

@@ -15,9 +15,10 @@ Consequences surfaced here:
   or an NI lane front), so there is no ``K`` to size, no overflow, and no
   regrow-and-rerun loop in the runner.
 * ``backend=`` selects the whole-cycle engine now, not just arbitration:
-  ``ref`` (jnp scan — the CPU fast path), ``pallas`` (fused chunk kernel,
-  TPU/GPU), ``pallas_interpret`` (kernel semantics on CPU, bit-identical
-  to ``ref`` — CI's validation path). It threads from ``NoCConfig.
+  ``ref`` (jnp scan — the default, and the engine that compiles for TPU),
+  ``pallas`` (fused chunk kernel; Mosaic does not lower it yet),
+  ``pallas_interpret`` (kernel semantics on CPU, bit-identical to ``ref``
+  — CI's validation path). It threads from ``NoCConfig.
   xsim_backend`` through ``xsimulate`` down to ``run_cycles``.
 * DPM children inject in dynamic parent-arrival order (the host sim's
   release-order queues), closing the old static-order fidelity delta.

@@ -18,7 +18,6 @@ import sys  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
 from jax.sharding import NamedSharding  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
@@ -35,15 +34,18 @@ def check_ep_matches_dense():
         moe=dataclasses.replace(cfg.moe, capacity_factor=8.0)
     )  # no drops => exact equality modulo reduction order
     p, _ = moe_init(jax.random.PRNGKey(0), cfg)
-    x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
-    y_dense, aux_d = moe_apply_dense(p, x, cfg)
-    set_mesh = getattr(jax, "set_mesh", None)  # jax<0.6: Mesh is the ctx mgr
-    with (set_mesh(mesh) if set_mesh else mesh):
-        y_ep, aux_e = moe_apply_ep(p, x, cfg, mesh)
-    np.testing.assert_allclose(
-        np.asarray(y_dense), np.asarray(y_ep), atol=2e-5
-    )
-    print("ep == dense: OK")
+    # tokens shard over B when the 8 shards tile it, else over S
+    for shape, spec in (((8, 16), P(("data", "model"))),
+                        ((4, 16), P(None, ("data", "model")))):
+        x = jax.random.normal(jax.random.PRNGKey(1), (*shape, cfg.d_model))
+        y_dense, aux_d = moe_apply_dense(p, x, cfg)
+        with jax.set_mesh(mesh):
+            y_ep, aux_e = moe_apply_ep(p, x, cfg, mesh)
+        assert y_ep.sharding.spec == spec, (shape, y_ep.sharding)
+        np.testing.assert_allclose(
+            np.asarray(y_dense), np.asarray(y_ep), atol=2e-5
+        )
+        print(f"ep == dense: OK (B, S) = {shape}, tokens on {spec}")
 
 
 def check_dpm_broadcast():
@@ -58,9 +60,9 @@ def check_dpm_broadcast():
     def fn(xl):
         return apply_schedule(xl, sched, "data")
 
-    out = shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-        check_rep=False,
+        check_vma=False,
     )(x)
     np.testing.assert_array_equal(np.asarray(out), np.zeros(8))
     print("dpm broadcast: OK (all ranks got rank-0 payload)")
@@ -84,12 +86,12 @@ def check_compressed_psum():
             jnp.sum(jnp.abs(e1))[None],
         )
 
-    s1, exact, errn = shard_map(
+    s1, exact, errn = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=P("data"),
         out_specs=(P("data"), P("data"), P("data")),
-        check_rep=False,
+        check_vma=False,
     )(g)
     rel = float(
         jnp.abs(s1 - exact).max() / jnp.abs(exact).max()
@@ -119,11 +121,14 @@ def check_pipeline_forward():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
     print("pipeline forward: OK")
 
-    # grads flow through the pipeline
+    # grads flow through the pipeline; make_mesh axes are Explicit, so the
+    # eager backward pass needs the mesh as context to place the replicated
+    # cotangents
     def loss(sp):
         return jnp.sum(pipeline_apply(layer_fn, sp, x, mesh, axis="pipe") ** 2)
 
-    gr = jax.grad(loss)(stage_params)
+    with jax.set_mesh(mesh):
+        gr = jax.grad(loss)(stage_params)
     assert bool(jnp.isfinite(gr).all()) and float(jnp.abs(gr).max()) > 0
     print("pipeline grad: OK")
 

@@ -110,7 +110,29 @@ def test_batched_plans_bit_identical_remaining_combos(algo, cm):
         assert pb == plan(algo, g, src, dests, cost_model=cm)
 
 
-@settings(max_examples=40)
+@pytest.mark.parametrize("fabric", [grid(16), torus(16, 16)],
+                         ids=["mesh16", "torus16"])
+def test_large_hop_fabrics_plan_on_device(fabric):
+    """A label-monotone C_p chain crosses at most NN - 1 links, so the f32
+    exactness bound admits hop-priced fabrics well past 8x8."""
+    bp = BatchPlanner(fabric, "DPM", "hops")
+    assert bp.support.ok, bp.support.reason
+    reqs = _requests(fabric, 12, seed=16, kmax=24)
+    for (src, dests), pb in zip(reqs, bp.plan_many(reqs)):
+        assert pb == plan("DPM", fabric, src, dests)
+    assert bp.info().host_plans == 0
+
+
+def test_dyadic_grain_is_the_least_power_of_two():
+    assert bpm._dyadic_grain([1.0, 62.0], [0.0]) == 1
+    assert bpm._dyadic_grain([0.5, 0.125]) == 8
+    assert bpm._dyadic_grain([1 / 256]) == 256
+    assert bpm._dyadic_grain([1 / 512]) is None
+    assert bpm._dyadic_grain([0.1]) is None
+    assert bpm._dyadic_grain([float("inf")]) is None
+
+
+@settings(max_examples=40, deadline=None)
 @given(st.integers(0, 10**9))
 def test_batched_plan_bit_identical_property(seed):
     """Property form: random (src, dest-set) instances on the shared mesh
@@ -274,6 +296,33 @@ def test_dist_schedule_builder_uses_arena_and_matches_host(monkeypatch):
     assert planner_for(t, "DPM").info().host_plans > 0
     assert sched.rounds == sched_host.rounds
     assert sched.hops == sched_host.hops
+
+
+def test_device_planning_inside_a_jit_trace():
+    """EP MoE builds its all-to-all schedule while the caller's jit
+    traces: the device planner still runs eagerly and returns plans, and
+    its cached device tables are arrays, not the trace's tracers."""
+    import jax
+
+    from repro.dist.multicast import schedule_multicasts
+
+    t = torus(4, 4)
+    reqs = [((0, 0), [(2, 2), (1, 3)]), ((3, 3), [(0, 1), (2, 0)])]
+    bp = BatchPlanner(t, "DPM")
+    got = {}
+
+    @jax.jit
+    def step(x):
+        got["plans"] = bp.plan_many(reqs)
+        got["sched"] = schedule_multicasts(t, reqs)
+        return x + 1
+
+    assert int(step(0)) == 1
+    assert got["plans"] == [plan("DPM", t, s, d) for s, d in reqs]
+    assert bp.info().batched_plans == len(reqs) and bp.info().host_plans == 0
+    assert bp.plan_many(_requests(t, 4, seed=3))  # tables usable after
+    arena_clear()
+    assert got["sched"].rounds == schedule_multicasts(t, reqs).rounds
 
 
 def test_xsim_compile_bulk_plans_through_arena():

@@ -1,4 +1,10 @@
-"""Launch-layer tests: HLO analyzer, mesh/spec builders (1-device view)."""
+"""Launch-layer tests: HLO analyzer, mesh/spec builders (1-device view),
+the compile-cache helper and the benchmark runner's exit code."""
+import os
+import pathlib
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -32,8 +38,6 @@ def test_analyzer_counts_scan_trip_counts():
     assert abs(a_scan["flops"] - a_unrl["flops"]) / a_unrl["flops"] < 0.05
     assert a_scan["flops"] >= expect
     xla = jax.jit(scanned).lower(w, x).compile().cost_analysis()
-    if isinstance(xla, (list, tuple)):  # older jax returns [dict]
-        xla = xla[0]
     assert xla["flops"] < expect / 4  # demonstrates the undercount
 
 
@@ -79,3 +83,67 @@ def test_model_flops_accounting():
     mf_train = model_flops(ARCHS["smollm-135m"], SHAPES["train_4k"], run)
     n = param_counts(ARCHS["smollm-135m"], run)["total"]
     assert abs(mf_train - 6 * n * 256 * 4096) / mf_train < 1e-6
+
+
+def test_importing_dryrun_leaves_xla_flags_alone():
+    before = os.environ.get("XLA_FLAGS")
+    import repro.launch.dryrun  # noqa: F401
+
+    assert os.environ.get("XLA_FLAGS") == before
+
+
+_CACHE_PROBE = """
+import jax, jax.numpy as jnp
+from repro.launch.compile_cache import enable_compile_cache
+print(enable_compile_cache())
+print(jax.config.jax_compilation_cache_dir)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.jit(lambda x: jnp.sin(x) * 3)(jnp.ones(7)).block_until_ready()
+"""
+
+
+@pytest.mark.parametrize("env_dir", [True, False], ids=["env", "checkout"])
+def test_compile_cache_dir(env_dir, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and is the only directory written;
+    without it the cache sits at <checkout>/.jax_cache."""
+    from repro.launch.compile_cache import CACHE_DIR
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert CACHE_DIR == root / ".jax_cache"
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=str(root / "src"))
+    want = str(tmp_path / "cc") if env_dir else str(CACHE_DIR)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = want
+    code = _CACHE_PROBE
+    if not env_dir:  # check where it points without compiling into it
+        code = code.split("jax.config.update(")[0]
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True,
+        text=True, timeout=300, cwd=tmp_path,
+    )
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == [want, want]
+    if env_dir:
+        assert any((tmp_path / "cc").iterdir())
+        assert [p.name for p in tmp_path.iterdir()] == ["cc"]
+
+
+def test_benchmark_runner_exits_nonzero_when_a_suite_raises(
+    monkeypatch, capsys
+):
+    import benchmarks.torus_planner as suite
+    from benchmarks import run as bench_run
+    from repro.launch import compile_cache
+
+    def boom(**_kw):
+        raise RuntimeError("suite failed")
+
+    monkeypatch.setattr(suite, "run", boom)
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "")
+    monkeypatch.setattr(sys, "argv", ["run", "--only", "torus"])
+    with pytest.raises(SystemExit) as exc:
+        bench_run.main()
+    assert exc.value.code not in (0, None)
+    assert "torus/ERROR,0,RuntimeError:suite failed" in capsys.readouterr().out
