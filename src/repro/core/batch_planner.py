@@ -34,11 +34,13 @@ from __future__ import annotations
 
 import functools
 import threading
+import time
 from collections import OrderedDict
 from typing import NamedTuple
 
 import numpy as np
 
+from ..obs import install_gc_clock, span
 from .algo import (
     get_algorithm,
     get_cost_model,
@@ -89,9 +91,13 @@ class _Support(NamedTuple):
 
 class ArenaInfo(NamedTuple):
     """Per-planner arena stats: lookup hits/misses, LRU bounds/evictions,
-    and *planning attribution* — how many misses were planned on device
+    *planning attribution* — how many misses were planned on device
     (``batched_plans``, in ``dispatches`` jitted batches) vs on the host
-    fallback path (``host_plans``)."""
+    fallback path (``host_plans``) — and host seconds: ``plan_s`` inside
+    ``plan_many`` in all, of which ``lookup_s`` on arena lookups,
+    ``dispatch_s`` packing and enqueuing device merges, ``sync_s`` blocked
+    on their outputs, ``decode_s`` decoding them and ``host_plan_s`` in
+    host ``plan()``."""
 
     hits: int
     misses: int
@@ -101,6 +107,12 @@ class ArenaInfo(NamedTuple):
     batched_plans: int
     host_plans: int
     dispatches: int
+    plan_s: float
+    lookup_s: float
+    dispatch_s: float
+    sync_s: float
+    decode_s: float
+    host_plan_s: float
 
 
 class ArenaCacheInfo(NamedTuple):
@@ -307,13 +319,20 @@ class BatchPlanner:
         self._batched = 0
         self._host = 0
         self._dispatches = 0
+        self._plan_s = self._lookup_s = self._dispatch_s = 0.0
+        self._sync_s = self._decode_s = self._host_plan_s = 0.0
+        install_gc_clock()
 
     # ------------------------------------------------------------- public
     def plan_many(self, requests) -> list[MulticastPlan]:
         """Plan ``[(src, dests), ...]``; returns plans in request order,
         each bit-identical to ``plan(algo, topo, src, dests, cost_model)``."""
         with self._lock:
-            return self._plan_many_locked(list(requests))
+            t0 = time.perf_counter()
+            try:
+                return self._plan_many_locked(list(requests))
+            finally:
+                self._plan_s += time.perf_counter() - t0
 
     def plan_one(self, src: Coord, dests) -> MulticastPlan:
         return self.plan_many([(src, dests)])[0]
@@ -322,6 +341,8 @@ class BatchPlanner:
         return ArenaInfo(
             self._hits, self._misses, self.maxsize, len(self._arena),
             self._evictions, self._batched, self._host, self._dispatches,
+            self._plan_s, self._lookup_s, self._dispatch_s, self._sync_s,
+            self._decode_s, self._host_plan_s,
         )
 
     def clear(self) -> None:
@@ -330,33 +351,39 @@ class BatchPlanner:
 
     # ------------------------------------------------------------ internal
     def _plan_many_locked(self, requests) -> list[MulticastPlan]:
-        keys = [
-            (tuple(src), canonical_dests(dests)) for src, dests in requests
-        ]
-        out: list[MulticastPlan | None] = [None] * len(keys)
-        missing: list[tuple] = []
-        first_at: dict[tuple, int] = {}
-        for i, key in enumerate(keys):
-            hit = self._arena.get(key)
-            if hit is not None:
-                self._arena.move_to_end(key)
-                self._hits += 1
-                out[i] = hit
-            else:
-                self._misses += 1
-                if key not in first_at:
-                    first_at[key] = len(missing)
-                    missing.append(key)
+        t0 = time.perf_counter()
+        with span("repro.planner.lookup"):
+            keys = [
+                (tuple(src), canonical_dests(dests)) for src, dests in requests
+            ]
+            out: list[MulticastPlan | None] = [None] * len(keys)
+            missing: list[tuple] = []
+            first_at: dict[tuple, int] = {}
+            for i, key in enumerate(keys):
+                hit = self._arena.get(key)
+                if hit is not None:
+                    self._arena.move_to_end(key)
+                    self._hits += 1
+                    out[i] = hit
+                else:
+                    self._misses += 1
+                    if key not in first_at:
+                        first_at[key] = len(missing)
+                        missing.append(key)
+        self._lookup_s += time.perf_counter() - t0
         if missing:
             if self.support.ok:
                 plans = self._plan_batch(missing)
                 self._batched += len(missing)
             else:
-                plans = [
-                    plan(self._algo, self.topo, src, list(dests),
-                         cost_model=self._cm)
-                    for src, dests in missing
-                ]
+                t0 = time.perf_counter()
+                with span("repro.planner.host_plan"):
+                    plans = [
+                        plan(self._algo, self.topo, src, list(dests),
+                             cost_model=self._cm)
+                        for src, dests in missing
+                    ]
+                self._host_plan_s += time.perf_counter() - t0
                 self._host += len(missing)
             for key, p in zip(missing, plans):
                 self._arena[key] = p
@@ -436,24 +463,33 @@ class BatchPlanner:
             keys[i : i + DISPATCH_CHUNK]
             for i in range(0, len(keys), DISPATCH_CHUNK)
         ]
+        t0 = time.perf_counter()
         # run eagerly even while a caller's jit traces (EP MoE builds its
         # all-to-all schedule inside the jitted step): plans are host data
-        with jax.ensure_compile_time_eval():
+        with span("repro.planner.dispatch"), jax.ensure_compile_time_eval():
             outs = [self._dispatch(ck) for ck in chunks]
+        t1 = time.perf_counter()
+        self._dispatch_s += t1 - t0
         self._dispatches += len(chunks)
         plans: list[MulticastPlan] = []
         for ck, out in zip(chunks, outs):
             # one bulk device->host sync + python-list conversion per chunk
             # (per-element numpy scalar indexing in decode costs more than
             # the whole transfer)
-            chosen, order, reps, modes = (
-                np.asarray(x).tolist() for x in out[:4]
-            )
-            plans.extend(
-                self._decode(src, dests, chosen[b], order[b], reps[b],
-                             modes[b])
-                for b, (src, dests) in enumerate(ck)
-            )
+            with span("repro.planner.sync"):
+                host = [np.asarray(x) for x in out[:4]]
+            t2 = time.perf_counter()
+            with span("repro.planner.decode"):
+                chosen, order, reps, modes = (x.tolist() for x in host)
+                plans.extend(
+                    self._decode(src, dests, chosen[b], order[b], reps[b],
+                                 modes[b])
+                    for b, (src, dests) in enumerate(ck)
+                )
+            t3 = time.perf_counter()
+            self._sync_s += t2 - t1
+            self._decode_s += t3 - t2
+            t1 = t3
         return plans
 
     def _uni(self, a: Coord, b: Coord) -> list[Coord]:

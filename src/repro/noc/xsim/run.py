@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from dataclasses import dataclass
 
 import jax
@@ -32,6 +31,7 @@ from ..simulator import SimStats
 from ..traffic import Workload
 from ...core.algo import available_algorithms, get_algorithm
 from ...core.topology import make_topology
+from ...obs import span
 from .compile import (
     CompiledTraffic,
     compile_workload,
@@ -123,7 +123,6 @@ class XSimResults:
     dtime: np.ndarray  # (B, P, S) int32
     ctr: np.ndarray  # (B, len(CTR)) int32
     crel: np.ndarray  # (B, C) bool
-    wall_s: float  # host compile + device execute, seconds
     epoch_len: int = 0  # telemetry bucket width (cycles)
     lutil: np.ndarray | None = None  # (B, E, L) per-epoch link flits
     rconf: np.ndarray | None = None  # (B, E, NN) per-epoch router conflicts
@@ -285,25 +284,25 @@ def xsimulate(
     backend = resolve_backend(
         cfg.xsim_backend if backend is None else backend
     )
-    t0 = time.monotonic()
     traffics: list[CompiledTraffic] = []
-    for wi, wl in enumerate(workloads):
-        wcfg = cfg
-        if broken_links_per_workload is not None:
-            faults = broken_links_per_workload[wi]
-            if faults is not None:
-                wcfg = dataclasses.replace(
-                    cfg, broken_links=tuple(faults)
+    with span("repro.xsim.lower"):
+        for wi, wl in enumerate(workloads):
+            wcfg = cfg
+            if broken_links_per_workload is not None:
+                faults = broken_links_per_workload[wi]
+                if faults is not None:
+                    wcfg = dataclasses.replace(
+                        cfg, broken_links=tuple(faults)
+                    )
+            for algo in resolved:
+                traffics.append(
+                    compile_workload(
+                        wcfg, wl, algo,
+                        pad_packets=pad_packets, pad_stages=pad_stages,
+                        cost_model=cost_model,
+                    )
                 )
-        for algo in resolved:
-            traffics.append(
-                compile_workload(
-                    wcfg, wl, algo,
-                    pad_packets=pad_packets, pad_stages=pad_stages,
-                    cost_model=cost_model,
-                )
-            )
-    ref, stacked = stack_traffic(traffics)
+        ref, stacked = stack_traffic(traffics)
     T = max(wl.horizon for wl in workloads) + drain_grace
     ND = int(stacked["dslot"].max()) + 1  # flat delivery-slot space
     # the engine's static F is the largest worm in the batch: it sizes the
@@ -311,14 +310,15 @@ def xsimulate(
     # ride the compiled ``flits`` table
     F = max(cfg.flits_per_packet, int(stacked["flits"].max()))
     stacked_j = {k: jnp.asarray(v) for k, v in stacked.items()}
-    out, devices = _run_sharded(
-        stacked_j,
-        T=T, F=F, V=cfg.vcs_per_class,
-        BD=cfg.buffer_depth, L=ref.num_links, NN=ref.num_nodes, ND=ND,
-        kind=ref.kind, n=ref.n, m=ref.m, params=ref.params, backend=backend,
-        epoch_len=epoch_len,
-    )
-    out = jax.tree_util.tree_map(np.asarray, out)  # blocks until ready
+    with span("repro.xsim.run"):
+        out, devices = _run_sharded(
+            stacked_j,
+            T=T, F=F, V=cfg.vcs_per_class,
+            BD=cfg.buffer_depth, L=ref.num_links, NN=ref.num_nodes, ND=ND,
+            kind=ref.kind, n=ref.n, m=ref.m, params=ref.params,
+            backend=backend, epoch_len=epoch_len,
+        )
+        out = jax.tree_util.tree_map(np.asarray, out)  # blocks until ready
     # scatter-compact flat delivery times -> the (B, P, S) view the results
     # object (and the parity tests) consume
     ds = stacked["dslot"]
@@ -328,7 +328,6 @@ def xsimulate(
         out["dtime"][np.arange(B)[:, None, None], np.clip(ds, 0, ND)],
         -1,
     ).astype(np.int32)
-    wall = time.monotonic() - t0
     return XSimResults(
         cfg=cfg,
         algos=tuple(a.name for a in resolved),
@@ -340,7 +339,6 @@ def xsimulate(
         dtime=dtime,
         ctr=out["ctr"],
         crel=out["crel"],
-        wall_s=wall,
         epoch_len=epoch_len,
         lutil=out["lutil"],
         rconf=out["rconf"],

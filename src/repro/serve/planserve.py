@@ -17,10 +17,12 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from concurrent.futures import Future
 
 from ..core.batch_planner import DISPATCH_CHUNK, ArenaInfo, planner_for
 from ..core.planner import MulticastPlan
+from ..obs import span
 from .engine import take_batch
 
 
@@ -32,6 +34,11 @@ class PlanServer:
     any number of producers may ``submit``/``prefetch`` concurrently.
     Usable as a context manager (``with PlanServer(topo) as ps: ...``) —
     exit closes with drain.
+
+    ``stats`` counts the batches and requests planned, and the seconds
+    those requests waited from ``submit``/``prefetch`` to admission into a
+    batch: ``queue_wait_s`` summed over them, ``queue_wait_max_s`` the
+    longest.
     """
 
     def __init__(self, topo, algo="DPM", cost_model=None, *,
@@ -44,7 +51,8 @@ class PlanServer:
         self.max_batch = max_batch
         self.max_wait_s = max_wait_s
         self.queue: queue.Queue[tuple] = queue.Queue()
-        self.stats = {"batches": 0, "requests": 0}
+        self.stats = {"batches": 0, "requests": 0, "queue_wait_s": 0.0,
+                      "queue_wait_max_s": 0.0}
         self._stop = threading.Event()
         self._thread = threading.Thread(
             target=self._run, name="planserve", daemon=True
@@ -66,7 +74,7 @@ class PlanServer:
         if self._stop.is_set():
             raise RuntimeError("PlanServer is closed")
         fut: "Future[MulticastPlan]" = Future()
-        self.queue.put((src, dests, fut))
+        self.queue.put((src, dests, fut, time.perf_counter()))
         return fut
 
     def prefetch(self, requests) -> None:
@@ -76,7 +84,7 @@ class PlanServer:
         if self._stop.is_set():
             raise RuntimeError("PlanServer is closed")
         for src, dests in requests:
-            self.queue.put((src, dests, None))
+            self.queue.put((src, dests, None, time.perf_counter()))
 
     def plan(self, src, dests) -> MulticastPlan:
         """Synchronous convenience wrapper: submit and wait."""
@@ -92,7 +100,7 @@ class PlanServer:
         if not drain:
             while True:
                 try:
-                    _, _, fut = self.queue.get_nowait()
+                    _, _, fut, _ = self.queue.get_nowait()
                 except queue.Empty:
                     break
                 if fut is not None:
@@ -108,23 +116,37 @@ class PlanServer:
 
     # ------------------------------------------------------------- worker
     def _run(self) -> None:
+        ordinal = 0
         while True:
-            batch = take_batch(
-                self.queue, self.max_batch, self.max_wait_s, stop=self._stop
-            )
+            with span("repro.planserve.wait"):
+                batch = take_batch(
+                    self.queue, self.max_batch, self.max_wait_s,
+                    stop=self._stop,
+                )
+            admitted = time.perf_counter()
             if not batch:  # stopped and drained
                 return
-            try:
-                plans = self.planner.plan_many(
-                    [(src, dests) for src, dests, _ in batch]
-                )
-            except Exception as e:  # propagate to every waiter, keep serving
-                for _, _, fut in batch:
-                    if fut is not None:
-                        fut.set_exception(e)
-                continue
-            self.stats["batches"] += 1
-            self.stats["requests"] += len(batch)
-            for (_, _, fut), p in zip(batch, plans):
+            with span("repro.planserve.batch", batch=ordinal, size=len(batch)):
+                ordinal += 1
+                self._serve(batch, admitted)
+
+    def _serve(self, batch: list, admitted: float) -> None:
+        try:
+            plans = self.planner.plan_many(
+                [(src, dests) for src, dests, _, _ in batch]
+            )
+        except Exception as e:  # propagate to every waiter, keep serving
+            for _, _, fut, _ in batch:
+                if fut is not None:
+                    fut.set_exception(e)
+            return
+        waits = [admitted - t for _, _, _, t in batch]
+        st = self.stats
+        st["batches"] += 1
+        st["requests"] += len(batch)
+        st["queue_wait_s"] += sum(waits)
+        st["queue_wait_max_s"] = max(st["queue_wait_max_s"], max(waits))
+        with span("repro.planserve.resolve"):
+            for (_, _, fut, _), p in zip(batch, plans):
                 if fut is not None:
                     fut.set_result(p)
