@@ -3,10 +3,12 @@
 ``plan()`` is a host-side Python loop behind an LRU — fine for one multicast
 at a time, not for serving-scale request streams where planning itself is
 the hot path. This module plans *batches*: pack B (src, dest-set) instances
-into ``(B, NN)`` destination masks, run Algorithm 1 for all of them in one
-jitted dispatch (``kernels.dpm_cost.dpm_plan_exact`` — full Definition 2,
-C_t and C_p, MU/DP modes, greedy pick order), and decode the resulting
-partition tensors into ``MulticastPlan``s only for arena misses.
+into ``(B, K)`` destination slots (node indices, -1 pads), run Algorithm 1
+for all of them in one jitted dispatch (``kernels.dpm_cost.dpm_plan_exact``
+— full Definition 2, C_t and C_p, MU/DP modes, greedy pick order, priced
+over each instance's K slots rather than every node of the fabric), and
+decode the resulting partition tensors into ``MulticastPlan``s only for
+arena misses.
 
 The correctness contract is **bit-identity with the host planner**: every
 decoded plan equals ``plan(algo, topo, src, dests, cost_model=...)`` field
@@ -18,7 +20,8 @@ for field. Three things make that hold:
 * a label-chain decomposition prices C_p exactly on device: a label-ordered
   chain is the concatenation of pairwise label routes between consecutive
   members (the dual-path rule never passes a pending member early), so C_p
-  reduces to a prefix scan over dense pairwise price matrices;
+  reduces to a prefix scan over pairwise price matrices, over the
+  label-sorted destination slots;
 * ``batch_support`` gates batching on *exactness*: every price must be a
   dyadic rational (multiple of 1/q, q a power of two <= 256) small enough
   that float32 sums stay exact, the cost model must price routes
@@ -74,6 +77,10 @@ DEFAULT_ARENA_SIZE = 65_536
 # on multi-core hosts the decode of chunk k overlaps the asynchronously
 # dispatched device compute of chunk k+1.
 DISPATCH_CHUNK = 512
+# Destination slots per instance: the chunk's largest destination set,
+# rounded up to a power of two and at least MIN_SLOTS, so the paper's
+# fanouts (up to 16) share one compiled shape per padded batch size.
+MIN_SLOTS = 16
 
 # Exactness gate: prices must be multiples of 1/q for a power of two
 # q <= SCALE, and bounded so that any candidate-cost sum stays inside
@@ -268,10 +275,9 @@ def batch_support(topo: MeshGrid, algo="DPM", cost_model=None) -> _Support:
 # The batched planner + arena
 # ---------------------------------------------------------------------------
 class _Tables(NamedTuple):
-    memb: np.ndarray
-    memb_rows: list  # memb as nested python lists (decode-side lookups)
-    labels_d: object  # device copies (jax arrays)
-    order_d: object
+    memb_rows: list  # membership table as nested python lists (decode)
+    memb_d: object  # device copies (jax arrays)
+    labels_d: object
     dist_d: object
     wuni_d: object
     wh_d: object
@@ -406,10 +412,9 @@ class BatchPlanner:
             labels = snake_labels(self.topo)
             memb = membership_table(self.topo)
             self._tables_cached = _Tables(
-                memb,
                 memb.tolist(),
+                jnp.asarray(memb),
                 jnp.asarray(labels),
-                jnp.asarray(np.argsort(labels).astype(np.int32)),
                 jnp.asarray(dist),
                 jnp.asarray(w_uni),
                 jnp.asarray(wh),
@@ -418,32 +423,29 @@ class BatchPlanner:
             )
         return self._tables_cached
 
-    def _dispatch(self, keys: list[tuple]):
+    def _dispatch(self, keys: list[tuple], k: int):
         """One jitted ``dpm_plan_exact`` call over ≤ DISPATCH_CHUNK keys,
-        padded to a power of two. Returns the device arrays *without*
-        synchronizing — JAX dispatch is asynchronous, so the caller can
-        keep issuing chunks (and decoding earlier ones) while XLA computes
-        this one in its own threadpool."""
+        padded to a power of two, each packed into ``k`` destination slots.
+        Returns the device arrays *without* synchronizing — JAX dispatch is
+        asynchronous, so the caller can keep issuing chunks (and decoding
+        earlier ones) while XLA computes this one in its own threadpool."""
         import jax.numpy as jnp
 
         from ..kernels.dpm_cost.ops import dpm_plan_exact
 
         t = self._tables()
-        g = self.topo
-        NN = g.num_nodes
+        idx = self.topo.idx
         Bp = 1 << max(0, len(keys) - 1).bit_length()
-        mask = np.zeros((Bp, NN), bool)
+        dests = np.full((Bp, k), -1, np.int32)
         sidx = np.zeros(Bp, np.int32)
-        for b, (src, dests) in enumerate(keys):
-            sidx[b] = g.idx(src)
-            for d in dests:
-                mask[b, g.idx(d)] = True
+        for b, (src, ds) in enumerate(keys):
+            sidx[b] = idx(src)
+            dests[b, : len(ds)] = [idx(d) for d in ds]
         return dpm_plan_exact(
-            jnp.asarray(mask),
+            jnp.asarray(dests),
             jnp.asarray(sidx),
-            jnp.asarray(t.memb[sidx]),
+            t.memb_d,
             t.labels_d,
-            t.order_d,
             t.dist_d,
             t.wuni_d,
             t.wh_d,
@@ -464,10 +466,16 @@ class BatchPlanner:
             for i in range(0, len(keys), DISPATCH_CHUNK)
         ]
         t0 = time.perf_counter()
+        ks = [
+            max(MIN_SLOTS,
+                1 << (max(len(ds) for _, ds in ck) - 1).bit_length())
+            for ck in chunks
+        ]
         # run eagerly even while a caller's jit traces (EP MoE builds its
         # all-to-all schedule inside the jitted step): plans are host data
-        with span("repro.planner.dispatch"), jax.ensure_compile_time_eval():
-            outs = [self._dispatch(ck) for ck in chunks]
+        with span("repro.planner.dispatch", k=max(ks)), \
+                jax.ensure_compile_time_eval():
+            outs = [self._dispatch(ck, k) for ck, k in zip(chunks, ks)]
         t1 = time.perf_counter()
         self._dispatch_s += t1 - t0
         self._dispatches += len(chunks)
@@ -537,7 +545,9 @@ class BatchPlanner:
         row = self._tables().memb_rows[g.idx(src)]
         parts: list[list[Coord]] = [[] for _ in range(self.np_)]
         for d in dests:
-            parts[row[g.idx(d)]].append(d)
+            w = row[g.idx(d)]
+            if w >= 0:  # the source itself is in no wedge: delivered
+                parts[w].append(d)
         picked = sorted(
             (ci for ci in range(len(cands)) if chosen[ci]),
             key=lambda ci: (order[ci], ci),

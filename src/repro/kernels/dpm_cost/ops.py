@@ -284,53 +284,20 @@ def dpm_plan_topo(
     return _greedy_merge(costs, reps, np_), costs, reps
 
 
-def _chain_cost(sel_l, bound, ascending, label_order, w_flat, rep, NN):
-    """Price one dual-path chain side for every (packet, position).
-
-    ``sel_l`` is the selection reordered to label rank; the side's members
-    are the selected ranks strictly beyond ``bound`` (the representative's
-    label) in the walk direction. A label-ordered chain decomposes into
-    pairwise label routes between consecutive members — the label rule only
-    ever moves through labels at or below (above, descending) the current
-    target, so no pending member is passed early — which turns C_p into a
-    prefix-scan over label rank: each member's predecessor is the running
-    max (min) of selected ranks before it, or the representative when none.
-    Returns (side cost (B,), side nonempty (B,)).
-    """
-    pos = jnp.arange(NN, dtype=jnp.int32)
-    if ascending:
-        active = sel_l & (pos[None, :] > bound[:, None])
-        walk = active
-        order_nodes = label_order
-    else:
-        active = sel_l & (pos[None, :] < bound[:, None])
-        walk = jnp.flip(active, axis=1)
-        order_nodes = jnp.flip(label_order)
-    idx_seq = jnp.where(walk, pos[None, :], -1)
-    run = jax.lax.cummax(idx_seq, axis=1)
-    prev = jnp.concatenate(
-        [jnp.full((run.shape[0], 1), -1, run.dtype), run[:, :-1]], axis=1
-    )
-    prev_node = jnp.where(
-        prev >= 0, jnp.take(order_nodes, jnp.clip(prev, 0)), rep[:, None]
-    )
-    cur_node = order_nodes[None, :]
-    contrib = jnp.take(w_flat, prev_node * NN + cur_node)
-    return (
-        jnp.sum(jnp.where(walk, contrib, 0.0), axis=1),
-        active.any(axis=1),
-    )
+def _pick(onehot, x):
+    """``x`` at the one slot ``onehot`` marks along its last axis, as a
+    where-sum: one term plus zeros, so exact in any dtype."""
+    return jnp.sum(jnp.where(onehot, x, jnp.zeros((), x.dtype)), axis=-1)
 
 
 @functools.partial(
     jax.jit, static_argnames=("np_", "overhead", "include_source_leg")
 )
 def dpm_plan_exact(
-    dest_mask: jax.Array,  # (B, NN) bool destination sets
+    dests: jax.Array,  # (B, K) int32 destination node indices, -1 pads
     src_idx: jax.Array,  # (B,) int32 Topology.idx of each source
-    part_of: jax.Array,  # (B, NN) int32 wedge membership (all nodes)
+    memb: jax.Array,  # (NN, NN) int32 wedge membership (membership_table)
     labels: jax.Array,  # (NN,) int32 snake labels
-    label_order: jax.Array,  # (NN,) int32 node index at each label rank
     dist: jax.Array,  # (NN, NN) provider-route hop counts
     w_uni: jax.Array,  # (NN, NN) unicast-route prices (C_t terms)
     w_high: jax.Array,  # (NN, NN) HIGH-subnetwork label-route prices
@@ -343,67 +310,98 @@ def dpm_plan_exact(
     """Algorithm 1 batched with the *full* Definition 2 objective.
 
     Unlike ``dpm_plan_topo`` (which prices candidates by C_t only), this
-    evaluates both C_t and C_p per candidate — C_p via the label-chain
-    prefix scan of ``_chain_cost`` over the dense pairwise label-route
-    price matrices — and records the MU/DP mode choice and the greedy
-    pick order, everything the host decode needs to rebuild each
-    ``MulticastPlan`` bit-identically (``core.batch_planner``; exactness
-    conditions in ``batch_support`` there). Returns
-    ``(chosen, order, reps, mode_mu, costs)``, all ``(B, 3 * np_)`` over
-    the ``candidate_ids_for`` axis.
-    """
-    import numpy as _np
+    evaluates both C_t and C_p per candidate and records the MU/DP mode
+    choice and the greedy pick order, everything the host decode needs to
+    rebuild each ``MulticastPlan`` bit-identically (``core.batch_planner``;
+    exactness conditions in ``batch_support`` there). Each instance is its
+    source and up to K destination slots. Returns ``(chosen, order, reps,
+    mode_mu, costs)``, all ``(B, 3 * np_)`` over the ``candidate_ids_for``
+    axis.
 
-    cands = candidate_ids_for(np_)
-    NC = len(cands)
-    B, NN = dest_mask.shape
+    Candidates are priced over the slots, not the fabric's nodes: the
+    slots are sorted by snake label (pads last), and every price a
+    candidate needs is a pair of destinations (or the source and one),
+    read once per instance into ``(B, K, K)`` tables from contiguous
+    ``(B * K, NN)`` row gathers. A column of those rows is picked by a
+    where-sum over the NN nodes, on the TPU several times faster than
+    element gathers; the work grows with ``K * K * NN`` per instance.
+    """
+    K = dests.shape[1]
+    NN = labels.shape[0]
     dist = dist.astype(jnp.int32)
-    w_uni = w_uni.astype(jnp.float32)
-    wh_flat = w_high.astype(jnp.float32).reshape(-1)
-    wl_flat = w_low.astype(jnp.float32).reshape(-1)
-    dsrc = jnp.take(dist, src_idx, axis=0)  # (B, NN)
-    w_src = jnp.take(w_uni, src_idx, axis=0)
-    # All candidates evaluated as one stacked (NC * B, NN) problem — a
-    # static candidate->wedge incidence table turns the per-candidate
-    # membership test into a single gather, and everything downstream is
-    # one tensor op per step instead of NC of them.
-    inc = _np.zeros((NC, np_), bool)
-    for ci, ids in enumerate(cands):
-        inc[ci, list(ids)] = True
-    member = jnp.take(jnp.asarray(inc), part_of, axis=1)  # (NC, B, NN)
-    sel = (dest_mask[None] & member).reshape(NC * B, NN)
-    any_sel = sel.any(1)
-    # Definition 1 representative: min (dist-to-src, label)
-    dsrc_t = jnp.broadcast_to(dsrc[None], (NC, B, NN)).reshape(NC * B, NN)
-    key = jnp.where(sel, dsrc_t * BIG + labels[None], jnp.int32(2**30))
-    rep = jnp.argmin(key, 1).astype(jnp.int32)
-    # C_t: one unicast worm per non-representative destination
-    w_rep = jnp.take(w_uni, rep, axis=0)  # (NC * B, NN) prices from rep
-    cnt = jnp.sum(sel.astype(jnp.float32), 1)
-    cost_mu = jnp.sum(jnp.where(sel, w_rep, 0.0), 1)
+    pos = jnp.arange(K, dtype=jnp.int32)
+    lab = jnp.take(labels, jnp.clip(dests, 0))
+    lab = jnp.where(dests >= 0, lab, jnp.int32(2**31 - 1))
+    lab, node = jax.lax.sort((lab, dests), dimension=1, num_keys=1)
+    valid = node >= 0
+    node = jnp.clip(node, 0)
+    col = node[:, :, None] == jnp.arange(NN, dtype=node.dtype)
+
+    def at_slots(table):  # (NN, NN) -> (B, K): table[source, slot]
+        return _pick(col, jnp.take(table, src_idx, axis=0)[:, None])
+
+    def pairs(table):  # (NN, NN) -> (B, K, K): table[slot i, slot j]
+        rows = jnp.take(table.astype(jnp.float32), node, axis=0)
+        return _pick(col[:, None], rows[:, :, None])
+
+    dsrc, part = at_slots(dist), at_slots(memb)
+    w_src = at_slots(w_uni.astype(jnp.float32))
+    wu, wh, wl = pairs(w_uni), pairs(w_high), pairs(w_low)
+    # membership: candidate ci holds slot k iff k's wedge bit is in ci's
+    # mask; the source (membership -1) and pads are in no candidate
+    bits = jnp.asarray(_cand_bits(np_))[:, None, None]
+    sel = (valid & (part >= 0))[None] & (
+        ((bits >> jnp.clip(part, 0)[None]) & 1) == 1
+    )  # (NC, B, K)
+    any_sel = sel.any(2)
+    # Definition 1 representative: min (dist-to-src, label); labels are
+    # unique, so the minimum is one slot
+    key = jnp.where(sel, (dsrc * BIG + lab)[None], jnp.int32(2**30))
+    r = jnp.argmin(key, axis=2).astype(jnp.int32)  # (NC, B)
+    at_r = r[..., None] == pos  # (NC, B, K)
+    rep = _pick(at_r, node[None])
+    rep_lab = _pick(at_r, lab[None])
+    # C_t: one unicast worm from the representative per member
+    w_rep = _pick(at_r[:, :, None, :], jnp.swapaxes(wu, 1, 2)[None])
+    cnt = jnp.sum(sel.astype(jnp.float32), 2)
+    cost_mu = jnp.sum(jnp.where(sel, w_rep, 0.0), 2)
     cost_mu = cost_mu + jnp.maximum(cnt - 1.0, 0.0) * float(overhead)
-    # C_p: label-ordered chains from the representative, one per side
-    rep_lab = jnp.take(labels, rep)
-    sel_l = jnp.take_along_axis(
-        sel, jnp.broadcast_to(label_order[None, :], sel.shape), axis=1
+    # C_p: a label-ordered chain is the concatenation of pairwise label
+    # routes between consecutive members (the label rule only ever moves
+    # through labels at or below (above, descending) the current target,
+    # so no pending member is passed early). So each side prices as a
+    # prefix scan over the label-sorted slots: a member's predecessor is
+    # the previous member on its side, or the representative.
+    act_h = sel & (lab[None] > rep_lab[..., None])
+    act_l = sel & (lab[None] < rep_lab[..., None])
+    run_h = jax.lax.cummax(jnp.where(act_h, pos, -1), axis=2)
+    prev_h = jnp.concatenate(
+        [jnp.full(run_h.shape[:2] + (1,), -1, jnp.int32), run_h[..., :-1]],
+        axis=2,
     )
-    hi, any_h = _chain_cost(sel_l, rep_lab, True, label_order, wh_flat, rep, NN)
-    lo, any_l = _chain_cost(sel_l, rep_lab, False, label_order, wl_flat, rep, NN)
-    cost_dp = (
-        hi + lo
-        + (any_h.astype(jnp.float32) + any_l.astype(jnp.float32))
-        * float(overhead)
+    run_l = jax.lax.cummin(jnp.where(act_l, pos, K), axis=2, reverse=True)
+    prev_l = jnp.concatenate(
+        [run_l[..., 1:], jnp.full(run_l.shape[:2] + (1,), K, jnp.int32)],
+        axis=2,
     )
+    prev_h = jnp.where(prev_h >= 0, prev_h, r[..., None])
+    prev_l = jnp.where(prev_l < K, prev_l, r[..., None])
+
+    def side(prev, act, w):  # sum over members of w[prev slot, slot]
+        step = _pick(prev[..., None] == pos, jnp.swapaxes(w, 1, 2)[None])
+        return jnp.sum(jnp.where(act, step, 0.0), 2), act.any(2)
+
+    (hi, any_h), (lo, any_l) = side(prev_h, act_h, wh), side(prev_l, act_l, wl)
+    cost_dp = hi + lo + (
+        any_h.astype(jnp.float32) + any_l.astype(jnp.float32)
+    ) * float(overhead)
     # ties prefer MU (the paper: D_H/D_L computation is then skipped)
     mode_mu = cost_mu <= cost_dp
     cost = jnp.minimum(cost_mu, cost_dp)
     if include_source_leg:
-        w_src_t = jnp.broadcast_to(
-            w_src[None], (NC, B, NN)
-        ).reshape(NC * B, NN)
-        cost = cost + jnp.take_along_axis(w_src_t, rep[:, None], 1)[:, 0]
-    costs = jnp.where(any_sel, cost, 0.0).reshape(NC, B).T
-    reps = jnp.where(any_sel, rep, -1).reshape(NC, B).T
-    modes = (mode_mu | ~any_sel).reshape(NC, B).T
+        cost = cost + _pick(at_r, w_src[None])
+    costs = jnp.where(any_sel, cost, 0.0).T
+    reps = jnp.where(any_sel, rep, -1).T
+    modes = (mode_mu | ~any_sel).T
     chosen, order = _greedy_merge_ordered(costs, reps, np_)
     return chosen, order, reps, modes, costs

@@ -366,3 +366,111 @@ def test_batch_padding_and_multi_chunk_batches():
     for i in sample:
         src, dests = reqs[i]
         assert got[i] == plan("DPM", g, src, dests)
+
+
+# ---------------------------------------------------------------------------
+# Destination-slot packing (kernels.dpm_cost.ops.dpm_plan_exact)
+# ---------------------------------------------------------------------------
+# 64-node fabrics: fanout 16 packs into MIN_SLOTS slots, 17 into twice
+# that; the 3-D kinds carry the 26-wedge candidate table
+SLOT_FABRICS = {
+    "mesh": grid(8),
+    "mesh3d": mesh3d(4, 4, 4, z_weight=2.0),
+    "torus3d": torus3d(4, 4, 4),
+    "chiplet": chiplet(8),
+}
+
+
+def _fanout_requests(g, n, fanout, seed):
+    nodes = g.nodes()
+    rng = random.Random(seed)
+    out = []
+    for _ in range(n):
+        src = rng.choice(nodes)
+        dests = rng.sample([x for x in nodes if x != src], fanout)
+        out.append((src, sorted(dests)))
+    return out
+
+
+def _merge(bp, reqs, k, bp_rows):
+    """``dpm_plan_exact``'s outputs for ``reqs`` packed as the planner
+    packs them, into ``k`` slots and ``bp_rows`` rows (pads all -1)."""
+    import numpy as np
+
+    from repro.kernels.dpm_cost.ops import dpm_plan_exact
+
+    g, t = bp.topo, bp._tables()
+    dests = np.full((bp_rows, k), -1, np.int32)
+    sidx = np.zeros(bp_rows, np.int32)
+    for b, (src, ds) in enumerate(reqs):
+        sidx[b] = g.idx(src)
+        dests[b, : len(ds)] = [g.idx(d) for d in ds]
+    out = dpm_plan_exact(
+        dests, sidx, t.memb_d, t.labels_d, t.dist_d, t.wuni_d, t.wh_d,
+        t.wl_d, np_=bp.np_, overhead=t.overhead,
+    )
+    return [np.asarray(x) for x in out]
+
+
+@pytest.mark.parametrize("fanout", [16, 17])
+@pytest.mark.parametrize("kind", sorted(SLOT_FABRICS))
+@pytest.mark.parametrize("algo,cm", [("DPM", "hops"), ("DPM-E", "weighted")])
+def test_slot_pricing_bit_identical_at_any_width(algo, cm, kind, fanout):
+    """Slot pricing == host ``plan()``, on either side of the MIN_SLOTS
+    step (fanout 16 packs into 16 slots, 17 into 32), and the same
+    instances packed into 64 slots price the same. Five instances pad to
+    a chunk of eight whose three pad rows are all -1 and choose nothing."""
+    g = SLOT_FABRICS[kind]
+    bp = BatchPlanner(g, algo, cm)
+    assert bp.support.ok, bp.support.reason
+    reqs = _fanout_requests(g, 5, fanout, seed=sum(map(ord, kind)) + fanout)
+    got = bp.plan_many(reqs)
+    for (src, dests), pb in zip(reqs, got):
+        assert pb == plan(algo, g, src, dests, cost_model=cm)
+    assert bp.info().dispatches == 1
+
+    k = bpm.MIN_SLOTS if fanout <= bpm.MIN_SLOTS else 2 * bpm.MIN_SLOTS
+    wide = _merge(bp, reqs, 4 * bpm.MIN_SLOTS, 8)
+    for a, b in zip(_merge(bp, reqs, k, 8), wide):
+        assert (a == b).all()
+    chosen, order, reps, modes, costs = wide
+    assert not chosen[5:].any() and (reps[5:] == -1).all()
+    assert (costs[5:] == 0).all() and modes[5:].all()
+
+
+def test_plan_many_packs_each_chunk_to_its_own_width(monkeypatch):
+    """One ``plan_many`` over two chunks, of fanouts 16 and 17: two
+    dispatches, at 16 and 32 slots, both equal to host ``plan()``."""
+    from repro.kernels.dpm_cost import ops
+
+    widths = []
+    real = ops.dpm_plan_exact
+
+    def spy(dests, *a, **kw):
+        widths.append(dests.shape[1])
+        return real(dests, *a, **kw)
+
+    monkeypatch.setattr(bpm, "DISPATCH_CHUNK", 4)
+    monkeypatch.setattr(ops, "dpm_plan_exact", spy)
+    g = SLOT_FABRICS["mesh"]
+    bp = BatchPlanner(g, "DPM")
+    reqs = (_fanout_requests(g, 4, 16, seed=1)
+            + _fanout_requests(g, 4, 17, seed=2))
+    before = bp.info()
+    got = bp.plan_many(reqs)
+    assert bp.info().dispatches - before.dispatches == 2
+    assert widths == [16, 32]
+    for (src, dests), pb in zip(reqs, got):
+        assert pb == plan("DPM", g, src, dests)
+
+
+@pytest.mark.parametrize("k", [16, 32])
+def test_source_among_dests_is_already_delivered(k):
+    """A destination list that holds the source plans as the host does:
+    the source is in no partition, at either slot width."""
+    g = SLOT_FABRICS["mesh"]
+    bp = BatchPlanner(g, "DPM")
+    reqs = [(src, sorted(set(dests) | {src}))
+            for src, dests in _fanout_requests(g, 3, k - 2, seed=k)]
+    for (src, dests), pb in zip(reqs, bp.plan_many(reqs)):
+        assert pb == plan("DPM", g, src, dests)

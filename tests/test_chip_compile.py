@@ -64,10 +64,14 @@ def _fits(compiled) -> int:
     return used
 
 
-@pytest.mark.parametrize("side", [8, 32])
-def test_dpm_plan_exact_compiles_for_v5e(side, one_chip, no_persistent_cache):
+@pytest.mark.parametrize(
+    "side,k", [(8, 16), (32, 16), (8, 32), (32, 32), (32, 64)]
+)
+def test_dpm_plan_exact_compiles_for_v5e(side, k, one_chip,
+                                         no_persistent_cache):
     """The batched planner's dispatch at DISPATCH_CHUNK, on the 8x8 serving
-    fabric and at MAX_ARENA_NODES (32x32)."""
+    fabric and at MAX_ARENA_NODES (32x32), with the paper's fanouts (16
+    destination slots) and with fanouts past 16 (32 and 64 slots)."""
     from repro.core.batch_planner import DISPATCH_CHUNK, MAX_ARENA_NODES
     from repro.kernels.dpm_cost.ops import dpm_plan_exact
 
@@ -75,11 +79,10 @@ def test_dpm_plan_exact_compiles_for_v5e(side, one_chip, no_persistent_cache):
     assert NN <= MAX_ARENA_NODES
     i32, f32 = jnp.int32, jnp.float32
     args = (
-        _spec((B, NN), jnp.bool_, one_chip),  # dest masks
+        _spec((B, k), i32, one_chip),  # destination slots
         _spec((B,), i32, one_chip),  # sources
-        _spec((B, NN), i32, one_chip),  # wedge membership
+        _spec((NN, NN), i32, one_chip),  # wedge membership
         _spec((NN,), i32, one_chip),  # snake labels
-        _spec((NN,), i32, one_chip),  # label order
         _spec((NN, NN), i32, one_chip),  # hop distances
         _spec((NN, NN), f32, one_chip),  # unicast prices
         _spec((NN, NN), f32, one_chip),  # HIGH label-route prices

@@ -148,14 +148,16 @@ def _hlo_module_name(lowered) -> str:
     return re.match(r"HloModule (\S+?),", lowered.as_text(dialect="hlo"))[1]
 
 
-def test_merge_module_keeps_the_name_trace_readers_match():
+@pytest.mark.parametrize("k", [16, 32])
+def test_merge_module_keeps_the_name_trace_readers_match(k):
+    """The merge lowers into the one module at any slot width."""
     from repro.kernels.dpm_cost.ops import dpm_plan_exact
 
-    B, NN = 4, 16
+    B, NN = 4, 64
     i32, f32 = jnp.int32, jnp.float32
-    shapes = [((B, NN), jnp.bool_), ((B,), i32), ((B, NN), i32), ((NN,), i32),
-              ((NN,), i32), ((NN, NN), i32), ((NN, NN), f32),
-              ((NN, NN), f32), ((NN, NN), f32)]
+    shapes = [((B, k), i32), ((B,), i32), ((NN, NN), i32), ((NN,), i32),
+              ((NN, NN), i32), ((NN, NN), f32), ((NN, NN), f32),
+              ((NN, NN), f32)]
     args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
     lowered = dpm_plan_exact.lower(*args, np_=8, overhead=0.0)
     assert "dpm_plan_exact" in _hlo_module_name(lowered)
