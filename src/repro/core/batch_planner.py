@@ -19,23 +19,29 @@ for field. Three things make that hold:
   representatives, modes, and pick order;
 * a label-chain decomposition prices C_p exactly on device: a label-ordered
   chain is the concatenation of pairwise label routes between consecutive
-  members (the dual-path rule never passes a pending member early), so C_p
-  reduces to a prefix scan over pairwise price matrices, over the
-  label-sorted destination slots;
+  members, skipping a member an earlier route already passed through. On a
+  healthy fabric the dual-path rule never passes a pending member early,
+  so C_p reduces to a prefix scan over pairwise price matrices, over the
+  label-sorted destination slots; on a degraded one a detour's BFS hops
+  may overshoot the target's label, and the device walks the slots in
+  label order with the nodes each pairwise route passes
+  (``label_chain_passes``);
 * ``batch_support`` gates batching on *exactness*: every price must be a
   dyadic rational (multiple of 1/q, q a power of two <= 256) small enough
-  that float32 sums stay exact, the cost model must price routes
-  edge-additively, and the fabric must be healthy (degraded topologies
-  detour through BFS fallback hops that break the chain decomposition —
-  those always take the host path).
+  that float32 sums stay exact, and the cost model must price routes
+  edge-additively.
 
-Anything outside the gate — degraded fabrics, non-dyadic objectives
-(energy), unregistered algorithms/models, oversized fabrics — falls back to
-the host ``plan()`` transparently; the arena caches either way.
+Degraded fabrics plan on the device like healthy ones, and their decoded
+plans are segmented into label-monotone worms as ``plan()`` segments them.
+Anything outside the gate — non-dyadic objectives (energy), unregistered
+algorithms/models, oversized fabrics — falls back to the host ``plan()``
+transparently; the arena caches either way.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
+import gc
 import threading
 import time
 from collections import OrderedDict
@@ -63,7 +69,7 @@ from .planner import (
     plan_dpm_e,
     segment_plan_for_faults,
 )
-from .routefn import provider_for, route_cost_matrices
+from .routefn import components, provider_for, route_cost_matrices
 from .routing import label_route, xy_route
 
 # Dense lowering is O(NN^2) host work (once per topology/model, cached);
@@ -91,6 +97,35 @@ _SCALE = 256
 _EXACT_LIMIT = float(2**24)
 
 
+# The cyclic collector is held off while a call decodes (``_plan_batch``).
+# A decode allocates a few hundred thousand containers that all survive
+# (the plans), so left alone the collector ran full collections every
+# call, walking the whole heap each time and freeing nothing: some 40 %
+# of a degraded 8x8 bulk window. Paused, the young plans are collected
+# once after the call. Reference counting still frees garbage meanwhile.
+# The pause nests across threads and restores the state it found.
+_PAUSE_LOCK = threading.Lock()
+_pauses = 0
+_resume = False
+
+
+@contextlib.contextmanager
+def _collector_paused():
+    global _pauses, _resume
+    with _PAUSE_LOCK:
+        if _pauses == 0:
+            _resume = gc.isenabled()
+            gc.disable()
+        _pauses += 1
+    try:
+        yield
+    finally:
+        with _PAUSE_LOCK:
+            _pauses -= 1
+            if _pauses == 0 and _resume:
+                gc.enable()
+
+
 class _Support(NamedTuple):
     ok: bool
     reason: str
@@ -104,7 +139,11 @@ class ArenaInfo(NamedTuple):
     ``plan_many`` in all, of which ``lookup_s`` on arena lookups,
     ``dispatch_s`` packing and enqueuing device merges, ``sync_s`` blocked
     on their outputs, ``decode_s`` decoding them and ``host_plan_s`` in
-    host ``plan()``."""
+    host ``plan()``. On fabrics whose plans are segmented into
+    label-monotone worms (degraded and BFS-routed ones), ``segment_s`` is
+    the part of ``decode_s`` spent segmenting device plans,
+    ``segmented_plans`` counts the plans segmentation changed and
+    ``relay_worms`` the worms it added."""
 
     hits: int
     misses: int
@@ -120,6 +159,9 @@ class ArenaInfo(NamedTuple):
     sync_s: float
     decode_s: float
     host_plan_s: float
+    segment_s: float
+    segmented_plans: int
+    relay_worms: int
 
 
 class ArenaCacheInfo(NamedTuple):
@@ -153,34 +195,57 @@ def membership_table(topo: MeshGrid) -> np.ndarray:
 def _label_chain_matrices_cached(topo: MeshGrid, cm) -> tuple:
     NN = topo.num_nodes
     nodes = topo.nodes()
+    idx = topo.idx
     provider = provider_for(topo)
+    comp = components(topo)
+    # only a fault-aware label step (a BFS hop) can pass a label beyond
+    # its target; the minimal rule never does
+    detours = bool(getattr(topo, "faults", ()))
     wh = np.zeros((NN, NN), np.float32)
     wl = np.zeros((NN, NN), np.float32)
+    passes: dict[tuple, int] = {}
     labels = {u: topo.label(*u) for u in nodes}
-    # Per target, one label_step call per source plus memoized chain
-    # resolution: cost[u] = link_cost(u, step(u)) + cost[step(u)] — O(NN)
-    # per target instead of re-walking every route (shared suffixes).
+    # Per target, one label_step call per node a walk visits plus memoized
+    # chain resolution: cost[u] = link_cost(u, step(u)) + cost[step(u)] —
+    # O(NN) per target instead of re-walking every route (shared
+    # suffixes). beyond[u] is the bitmask (over node indices) of the nodes
+    # the route u -> v visits past v's label.
     for v in nodes:
-        iv = topo.idx(v)
+        iv, lv = idx(v), labels[v]
         for high, w in ((True, wh), (False, wl)):
-            srcs = [
-                u for u in nodes
-                if (labels[u] < labels[v]) == high and u != v
-            ]
-            nxt = {u: provider.label_step(topo, u, v, high) for u in srcs}
+            nxt: dict[Coord, Coord] = {}
             cost: dict[Coord, float] = {v: 0.0}
-            for u in srcs:
+            beyond: dict[Coord, int] = {v: 0}
+            for u in nodes:
+                if (u == v or (labels[u] < lv) != high
+                        or comp[idx(u)] != comp[iv]):
+                    continue
                 stack = []
                 cur = u
                 while cur not in cost:
                     stack.append(cur)
+                    if cur not in nxt:
+                        nxt[cur] = provider.label_step(topo, cur, v, high)
                     cur = nxt[cur]
-                c = cost[cur]
+                c, m = cost[cur], beyond[cur]
                 for s in reversed(stack):
-                    c = cm.link_cost(topo, s, nxt[s]) + c
-                    cost[s] = c
-                w[topo.idx(u), iv] = cost[u]
-    return wh, wl
+                    t = nxt[s]
+                    c = cm.link_cost(topo, s, t) + c
+                    if detours and (labels[t] > lv if high else labels[t] < lv):
+                        m |= 1 << idx(t)
+                    cost[s], beyond[s] = c, m
+                w[idx(u), iv] = cost[u]
+                if beyond[u]:
+                    passes[(high, idx(u), iv)] = beyond[u]
+    if not passes:
+        return wh, wl, None
+    words = -(-NN // 32)
+    ph = np.zeros((NN, NN, words), np.uint32)
+    pl = np.zeros((NN, NN, words), np.uint32)
+    for (high, iu, iv), m in passes.items():
+        for k in range(words):
+            (ph if high else pl)[iu, iv, k] = (m >> (32 * k)) & 0xFFFFFFFF
+    return wh, wl, (ph.view(np.int32), pl.view(np.int32))
 
 
 def label_chain_matrices(topo: MeshGrid, cost_model=None):
@@ -188,7 +253,18 @@ def label_chain_matrices(topo: MeshGrid, cost_model=None):
     HIGH-subnetwork label route u -> v (defined for label(v) > label(u)),
     ``wl`` the LOW mirror — the tensors ``dpm_plan_exact``'s C_p chain
     scan gathers from. Cached per (topology, model) instance pair."""
-    return _label_chain_matrices_cached(topo, get_cost_model(cost_model))
+    return _label_chain_matrices_cached(topo, get_cost_model(cost_model))[:2]
+
+
+def label_chain_passes(topo: MeshGrid, cost_model=None):
+    """``(ph, pl)``, ``(NN, NN, ceil(NN / 32))`` int32 bitmasks over node
+    indices: bit ``w`` of ``ph[u, v]`` is set when the HIGH label route
+    u -> v passes node ``w`` with a label above v's (``pl`` the LOW
+    mirror, below). A chain passing a later member that way delivers it
+    early and skips its own segment to it (``routing.path_multicast``).
+    None when no route passes its target's label: always on a healthy
+    fabric, whose label rule never moves beyond its target."""
+    return _label_chain_matrices_cached(topo, get_cost_model(cost_model))[2]
 
 
 def _dyadic_grain(*arrays) -> int | None:
@@ -213,7 +289,8 @@ def _dyadic_grain(*arrays) -> int | None:
 def batch_support(topo: MeshGrid, algo="DPM", cost_model=None) -> _Support:
     """Can (topo, algo, cost_model) plan on the batched device path with
     the bit-identity guarantee? Returns (ok, reason) — the reason names the
-    first failed gate, and callers fall back to host ``plan()`` on any."""
+    first failed gate, and callers fall back to host ``plan()`` on any.
+    Broken links fail no gate: every fault pattern prices exactly."""
     a = get_algorithm(algo)
     if getattr(a, "_fn", None) not in (plan_dpm, plan_dpm_e):
         return _Support(False, f"algorithm {a.name!r} has no device twin")
@@ -224,10 +301,8 @@ def batch_support(topo: MeshGrid, algo="DPM", cost_model=None) -> _Support:
     )
     if not is_registered_cost_model(cm):
         return _Support(False, f"cost model {cm.name!r} not registered")
-    if getattr(topo, "faults", ()):
-        # BFS fallback hops on detoured label routes break the chain
-        # decomposition; degraded fabrics always plan on the host.
-        return _Support(False, "degraded topology (broken links)")
+    # degraded fabrics pass: detours are in the price tables, and a label
+    # route that passes a later chain member is in label_chain_passes
     if topo.num_nodes > MAX_ARENA_NODES:
         return _Support(
             False,
@@ -239,26 +314,29 @@ def batch_support(topo: MeshGrid, algo="DPM", cost_model=None) -> _Support:
     if int(dist.max(initial=0)) * BIG + topo.num_nodes >= 2**31:
         return _Support(False, "route distances overflow the int32 rep key")
     wh, wl = label_chain_matrices(topo, cm)
+    # pairs with no route (a failed router) price +inf and are never read
+    w_uni = w_uni[np.isfinite(w_uni)]
     q = _dyadic_grain(w_uni, wh, wl, [overhead])
     if q is None:
         return _Support(
             False, f"cost model {cm.name!r} prices are not dyadic (f32-exact)"
         )
     # Largest value any candidate sum reaches: C_t is at most NN unicast
-    # prices plus overheads; a C_p chain is label-monotone, so at most
-    # NN - 1 links per side, each priced at most max(w_uni); the greedy
-    # merge adds the costs of disjoint singles (C_t over disjoint members)
-    # plus their source legs. 4 NN (max(w_uni) + overhead + 1) covers all.
-    bound = q * (
-        4.0 * topo.num_nodes * (w_uni.max(initial=0) + overhead + 1.0)
-    )
+    # prices plus overheads; a C_p chain is at most NN pairwise label
+    # routes, each priced at most max(wh, wl) (on a healthy fabric a chain
+    # is label-monotone, at most NN - 1 links); the greedy merge adds the
+    # costs of disjoint singles (C_t over disjoint members) plus their
+    # source legs. 4 NN (max price + overhead + 1) covers all.
+    top = max(w_uni.max(initial=0), wh.max(initial=0), wl.max(initial=0))
+    bound = q * (4.0 * topo.num_nodes * (top + overhead + 1.0))
     if bound >= _EXACT_LIMIT:
         return _Support(False, "cost magnitudes exceed the f32-exact range")
     # edge-additivity spot check: the chain decomposition (and the per-edge
     # matrix build) assumes route_cost == sum of link_cost over the route
     nodes = topo.nodes()
+    comp = components(topo)
     for v in nodes[:: max(1, len(nodes) // 8)]:
-        if v == nodes[0]:
+        if v == nodes[0] or comp[topo.idx(v)] != comp[0]:
             continue
         route = provider_for(topo).unicast(topo, nodes[0], v)
         edge_sum = sum(
@@ -282,6 +360,8 @@ class _Tables(NamedTuple):
     wuni_d: object
     wh_d: object
     wl_d: object
+    ph_d: object  # label_chain_passes on the device, or None
+    pl_d: object
     overhead: float
 
 
@@ -294,7 +374,9 @@ class BatchPlanner:
     jitted ``dpm_plan_exact`` dispatch over all unique misses, then host
     decode of the partition tensors. When ``support.ok`` is False every
     miss plans through host ``plan()`` instead (same results, same arena).
-    Thread-safe: the plan server and direct callers may share an instance.
+    On a degraded fabric a request whose destinations the source cannot
+    reach raises ``DisconnectedError``, as ``plan()`` does. Thread-safe:
+    the plan server and direct callers may share an instance.
     """
 
     def __init__(self, topo: MeshGrid, algo="DPM", cost_model=None,
@@ -308,7 +390,14 @@ class BatchPlanner:
         self.maxsize = maxsize
         self.np_ = len(wedge_patterns(len(topo.from_idx(0))))
         self._cands = candidate_ids_for(self.np_)
-        self.support = batch_support(topo, self._algo, self._cm)
+        with span("repro.planner.tables"):
+            self.support = batch_support(topo, self._algo, self._cm)
+        # plan() segments plans on these fabrics; so does the decode
+        self._segments = bool(getattr(topo, "faults", ())) or getattr(
+            topo, "needs_bfs_routes", False
+        )
+        comp = components(topo)
+        self._comp = comp.tolist() if comp.max(initial=0) > 0 else None
         self._arena: "OrderedDict[tuple, MulticastPlan]" = OrderedDict()
         self._lock = threading.Lock()
         self._tables_cached: _Tables | None = None
@@ -327,6 +416,8 @@ class BatchPlanner:
         self._dispatches = 0
         self._plan_s = self._lookup_s = self._dispatch_s = 0.0
         self._sync_s = self._decode_s = self._host_plan_s = 0.0
+        self._segment_s = 0.0
+        self._segmented = self._relays = 0
         install_gc_clock()
 
     # ------------------------------------------------------------- public
@@ -348,7 +439,8 @@ class BatchPlanner:
             self._hits, self._misses, self.maxsize, len(self._arena),
             self._evictions, self._batched, self._host, self._dispatches,
             self._plan_s, self._lookup_s, self._dispatch_s, self._sync_s,
-            self._decode_s, self._host_plan_s,
+            self._decode_s, self._host_plan_s, self._segment_s,
+            self._segmented, self._relays,
         )
 
     def clear(self) -> None:
@@ -379,6 +471,8 @@ class BatchPlanner:
         self._lookup_s += time.perf_counter() - t0
         if missing:
             if self.support.ok:
+                if self._comp is not None:
+                    self._check_reachable(missing)
                 plans = self._plan_batch(missing)
                 self._batched += len(missing)
             else:
@@ -401,26 +495,42 @@ class BatchPlanner:
                     out[i] = plans[first_at[key]]
         return out  # type: ignore[return-value]
 
+    def _check_reachable(self, keys: list[tuple]) -> None:
+        """Raise host ``plan()``'s ``DisconnectedError`` for the first
+        request whose source cannot reach all of its destinations."""
+        comp, idx = self._comp, self.topo.idx
+        for src, dests in keys:
+            c = comp[idx(src)]
+            if any(comp[idx(d)] != c for d in dests):
+                plan(self._algo, self.topo, src, list(dests),
+                     cost_model=self._cm)
+
     def _tables(self) -> _Tables:
         if self._tables_cached is None:
             import jax.numpy as jnp
 
             from ..kernels.dpm_cost.ops import snake_labels
 
-            dist, w_uni, overhead = route_cost_matrices(self.topo, self._cm)
-            wh, wl = label_chain_matrices(self.topo, self._cm)
-            labels = snake_labels(self.topo)
-            memb = membership_table(self.topo)
-            self._tables_cached = _Tables(
-                memb.tolist(),
-                jnp.asarray(memb),
-                jnp.asarray(labels),
-                jnp.asarray(dist),
-                jnp.asarray(w_uni),
-                jnp.asarray(wh),
-                jnp.asarray(wl),
-                float(overhead),
-            )
+            with span("repro.planner.tables"):
+                dist, w_uni, overhead = route_cost_matrices(
+                    self.topo, self._cm)
+                wh, wl = label_chain_matrices(self.topo, self._cm)
+                passes = label_chain_passes(self.topo, self._cm)
+                ph, pl = (None, None) if passes is None else passes
+                labels = snake_labels(self.topo)
+                memb = membership_table(self.topo)
+                self._tables_cached = _Tables(
+                    memb.tolist(),
+                    jnp.asarray(memb),
+                    jnp.asarray(labels),
+                    jnp.asarray(dist),
+                    jnp.asarray(w_uni),
+                    jnp.asarray(wh),
+                    jnp.asarray(wl),
+                    None if ph is None else jnp.asarray(ph),
+                    None if pl is None else jnp.asarray(pl),
+                    float(overhead),
+                )
         return self._tables_cached
 
     def _dispatch(self, keys: list[tuple], k: int):
@@ -450,6 +560,8 @@ class BatchPlanner:
             t.wuni_d,
             t.wh_d,
             t.wl_d,
+            t.ph_d,
+            t.pl_d,
             np_=self.np_,
             overhead=t.overhead,
         )
@@ -480,25 +592,40 @@ class BatchPlanner:
         self._dispatch_s += t1 - t0
         self._dispatches += len(chunks)
         plans: list[MulticastPlan] = []
-        for ck, out in zip(chunks, outs):
-            # one bulk device->host sync + python-list conversion per chunk
-            # (per-element numpy scalar indexing in decode costs more than
-            # the whole transfer)
-            with span("repro.planner.sync"):
-                host = [np.asarray(x) for x in out[:4]]
-            t2 = time.perf_counter()
-            with span("repro.planner.decode"):
-                chosen, order, reps, modes = (x.tolist() for x in host)
-                plans.extend(
-                    self._decode(src, dests, chosen[b], order[b], reps[b],
-                                 modes[b])
-                    for b, (src, dests) in enumerate(ck)
-                )
-            t3 = time.perf_counter()
-            self._sync_s += t2 - t1
-            self._decode_s += t3 - t2
-            t1 = t3
+        with _collector_paused():  # the decoded plans all survive
+            for ck, out in zip(chunks, outs):
+                # one bulk device->host sync + python-list conversion per chunk
+                # (per-element numpy scalar indexing in decode costs more than
+                # the whole transfer)
+                with span("repro.planner.sync"):
+                    host = [np.asarray(x) for x in out[:4]]
+                t2 = time.perf_counter()
+                with span("repro.planner.decode"):
+                    chosen, order, reps, modes = (x.tolist() for x in host)
+                    got = [
+                        self._decode(src, dests, chosen[b], order[b], reps[b],
+                                     modes[b])
+                        for b, (src, dests) in enumerate(ck)
+                    ]
+                    plans.extend(self._segment(got) if self._segments else got)
+                t3 = time.perf_counter()
+                self._sync_s += t2 - t1
+                self._decode_s += t3 - t2
+                t1 = t3
         return plans
+
+    def _segment(self, plans: list[MulticastPlan]) -> list[MulticastPlan]:
+        """``segment_plan_for_faults`` over one decoded chunk, as ``plan()``
+        segments every plan on a degraded or BFS-routed fabric."""
+        t0 = time.perf_counter()
+        with span("repro.planner.segment"):
+            out = [segment_plan_for_faults(p, self.topo) for p in plans]
+        self._segment_s += time.perf_counter() - t0
+        for p, q in zip(plans, out):
+            if q is not p:
+                self._segmented += 1
+                self._relays += len(q.paths) - len(p.paths)
+        return out
 
     def _uni(self, a: Coord, b: Coord) -> list[Coord]:
         """Memoized ``xy_route`` (fresh list per call — plans own their
@@ -511,10 +638,10 @@ class BatchPlanner:
     def _chain(self, cur: Coord, dests, *, high: bool) -> list[Coord]:
         """Memoized ``path_multicast`` equivalent: the label-ordered chain
         is the concatenation of pairwise label routes between consecutive
-        label-sorted members — the same decomposition ``dpm_plan_exact``
-        prices C_p with, valid here because the support gate restricts the
-        batched path to minimal (label-monotone) route providers, where a
-        chain segment never passes a later pending destination early."""
+        label-sorted members, skipping a member an earlier route passed
+        through (delivered there) — the same decomposition
+        ``dpm_plan_exact`` prices C_p with. Only a detour passes a later
+        member, so healthy fabrics skip the check."""
         g = self.topo
         pending = [d for d in dests if d != cur]
         if not pending:
@@ -523,7 +650,10 @@ class BatchPlanner:
             self._labmap.update((u, g.label(*u)) for u in g.nodes())
         pending.sort(key=self._labmap.__getitem__, reverse=not high)
         path = [cur]
+        seen = set() if self._tables().ph_d is not None else None
         for t in pending:
+            if seen is not None and t in seen:
+                continue
             key = (path[-1], t, high)
             seg = self._seg_memo.get(key)
             if seg is None:
@@ -531,6 +661,8 @@ class BatchPlanner:
                     label_route(g, path[-1], t, high)[1:]
                 )
             path.extend(seg)
+            if seen is not None:
+                seen.update(seg)
         return path
 
     def _decode(self, src, dests, chosen, order, reps, modes) -> MulticastPlan:
@@ -571,8 +703,6 @@ class BatchPlanner:
                 p, g, src, union, rep, mode,
                 unicast=self._uni, chain=self._chain,
             )
-        if getattr(g, "needs_bfs_routes", False):
-            p = segment_plan_for_faults(p, g)
         return p
 
 

@@ -480,16 +480,39 @@ def provider_for(topo: MeshGrid) -> RouteProvider:
 # Dense lowering for the weighted Pallas planner kernel
 # ---------------------------------------------------------------------------
 @functools.lru_cache(maxsize=256)
+def components(topo: MeshGrid) -> np.ndarray:
+    """(NN,) int32 connected-component id of every node, ``Topology.idx``
+    order: all zeros on a healthy fabric; on a degraded one two nodes
+    share an id iff a route joins them (a failed router is a component of
+    its own)."""
+    comp = np.zeros(topo.num_nodes, np.int32)
+    if not isinstance(topo, FaultyTopology):
+        return comp
+    comp[:] = -1
+    for u in topo.nodes():
+        if comp[topo.idx(u)] < 0:
+            cid = comp.max() + 1
+            for v in _bfs_from(topo, u):
+                comp[topo.idx(v)] = cid
+    return comp
+
+
+@functools.lru_cache(maxsize=256)
 def _route_cost_matrices_cached(topo: MeshGrid, cm) -> tuple:
     NN = topo.num_nodes
     nodes = topo.nodes()
     dist = np.zeros((NN, NN), np.int32)
     weight = np.zeros((NN, NN), np.float32)
+    comp = components(topo)
     provider = provider_for(topo)
     for u in nodes:
         iu = topo.idx(u)
         for v in nodes:
             if u == v:
+                continue
+            if comp[iu] != comp[topo.idx(v)]:
+                dist[iu, topo.idx(v)] = -1
+                weight[iu, topo.idx(v)] = np.inf
                 continue
             route = provider.unicast(topo, u, v)
             dist[iu, topo.idx(v)] = len(route) - 1
@@ -514,7 +537,9 @@ def route_cost_matrices(
 
     Node indices are row-major (``Topology.idx``), matching the kernel's
     numbering. Results are cached per (topology, model) instance pair — both
-    are interned/registered singletons in normal use. Unreachable pairs on a
-    degraded topology raise ``DisconnectedError``.
+    are interned/registered singletons in normal use. A pair with no route
+    on a degraded topology (a failed router, a cut-off region) holds
+    ``dist`` -1 and ``weight`` +inf; routing such a pair raises
+    ``DisconnectedError``.
     """
     return _route_cost_matrices_cached(topo, cost_model)

@@ -302,6 +302,8 @@ def dpm_plan_exact(
     w_uni: jax.Array,  # (NN, NN) unicast-route prices (C_t terms)
     w_high: jax.Array,  # (NN, NN) HIGH-subnetwork label-route prices
     w_low: jax.Array,  # (NN, NN) LOW-subnetwork label-route prices
+    pass_high: jax.Array | None = None,  # (NN, NN, W) int32 bitmasks
+    pass_low: jax.Array | None = None,  # (label_chain_passes), or None
     *,
     np_: int,
     overhead: float = 0.0,
@@ -325,6 +327,12 @@ def dpm_plan_exact(
     ``(B * K, NN)`` row gathers. A column of those rows is picked by a
     where-sum over the NN nodes, on the TPU several times faster than
     element gathers; the work grows with ``K * K * NN`` per instance.
+
+    ``pass_high`` / ``pass_low`` (``core.batch_planner.label_chain_passes``)
+    are given on a degraded fabric, where a detoured label route may pass
+    a later chain member: C_p is then priced by a walk over the slots in
+    label order that skips members already passed. Without them (a
+    healthy fabric) the program is the prefix scan alone.
     """
     K = dests.shape[1]
     NN = labels.shape[0]
@@ -367,31 +375,70 @@ def dpm_plan_exact(
     cost_mu = jnp.sum(jnp.where(sel, w_rep, 0.0), 2)
     cost_mu = cost_mu + jnp.maximum(cnt - 1.0, 0.0) * float(overhead)
     # C_p: a label-ordered chain is the concatenation of pairwise label
-    # routes between consecutive members (the label rule only ever moves
-    # through labels at or below (above, descending) the current target,
-    # so no pending member is passed early). So each side prices as a
-    # prefix scan over the label-sorted slots: a member's predecessor is
-    # the previous member on its side, or the representative.
+    # routes between consecutive members. On a healthy fabric the label
+    # rule only ever moves through labels at or below (above, descending)
+    # the current target, so no pending member is passed early, and each
+    # side prices as a prefix scan over the label-sorted slots: a
+    # member's predecessor is the previous member on its side, or the
+    # representative. A detour may pass a later member, which the chain
+    # then skips: the walk below.
     act_h = sel & (lab[None] > rep_lab[..., None])
     act_l = sel & (lab[None] < rep_lab[..., None])
-    run_h = jax.lax.cummax(jnp.where(act_h, pos, -1), axis=2)
-    prev_h = jnp.concatenate(
-        [jnp.full(run_h.shape[:2] + (1,), -1, jnp.int32), run_h[..., :-1]],
-        axis=2,
-    )
-    run_l = jax.lax.cummin(jnp.where(act_l, pos, K), axis=2, reverse=True)
-    prev_l = jnp.concatenate(
-        [run_l[..., 1:], jnp.full(run_l.shape[:2] + (1,), K, jnp.int32)],
-        axis=2,
-    )
-    prev_h = jnp.where(prev_h >= 0, prev_h, r[..., None])
-    prev_l = jnp.where(prev_l < K, prev_l, r[..., None])
+    if pass_high is None:
+        run_h = jax.lax.cummax(jnp.where(act_h, pos, -1), axis=2)
+        prev_h = jnp.concatenate(
+            [jnp.full(run_h.shape[:2] + (1,), -1, jnp.int32),
+             run_h[..., :-1]],
+            axis=2,
+        )
+        run_l = jax.lax.cummin(jnp.where(act_l, pos, K), axis=2,
+                               reverse=True)
+        prev_l = jnp.concatenate(
+            [run_l[..., 1:], jnp.full(run_l.shape[:2] + (1,), K, jnp.int32)],
+            axis=2,
+        )
+        prev_h = jnp.where(prev_h >= 0, prev_h, r[..., None])
+        prev_l = jnp.where(prev_l < K, prev_l, r[..., None])
 
-    def side(prev, act, w):  # sum over members of w[prev slot, slot]
-        step = _pick(prev[..., None] == pos, jnp.swapaxes(w, 1, 2)[None])
-        return jnp.sum(jnp.where(act, step, 0.0), 2), act.any(2)
+        def side(prev, act, w):  # sum over members of w[prev slot, slot]
+            step = _pick(prev[..., None] == pos, jnp.swapaxes(w, 1, 2)[None])
+            return jnp.sum(jnp.where(act, step, 0.0), 2), act.any(2)
 
-    (hi, any_h), (lo, any_l) = side(prev_h, act_h, wh), side(prev_l, act_l, wl)
+        hi, any_h = side(prev_h, act_h, wh)
+        lo, any_l = side(prev_l, act_l, wl)
+    else:
+        def passes(table):  # (NN, NN, W) -> (B, K, K, K)
+            # [b, i, k, j]: the label route slot i -> slot k passes slot j
+            words = jnp.take(
+                table.reshape(NN * NN, -1),
+                node[:, :, None] * NN + node[:, None, :], axis=0,
+            )  # (B, K, K, W)
+            at_w = (node // 32)[..., None] == jnp.arange(table.shape[2])
+            word = _pick(at_w[:, None, None], words[:, :, :, None])
+            bit = (word >> (node % 32)[:, None, None]) & 1
+            return (bit == 1) & valid[:, None, None]
+
+        def walk(act, w, ps, reverse):
+            # the chain visits its members in label order from the
+            # representative, skipping a member an earlier route passed
+            def step(carry, x):
+                cur, seen, cost = carry
+                act_k, w_k, ps_k, k = x
+                at_cur = cur[..., None] == pos  # (NC, B, K)
+                go = act_k & ~jnp.any(seen & (pos == k), axis=2)
+                cost = cost + jnp.where(go, _pick(at_cur, w_k[None]), 0.0)
+                passed = jnp.any(at_cur[..., None] & ps_k[None], axis=2)
+                seen = seen | (go[..., None] & passed)
+                return (jnp.where(go, k, cur), seen, cost), None
+
+            xs = (jnp.moveaxis(act, 2, 0), jnp.moveaxis(w, 2, 0),
+                  jnp.moveaxis(ps, 2, 0), pos)
+            init = (r, jnp.zeros_like(act), jnp.zeros(r.shape, jnp.float32))
+            (_, _, cost), _ = jax.lax.scan(step, init, xs, reverse=reverse)
+            return cost, act.any(2)
+
+        hi, any_h = walk(act_h, wh, passes(pass_high), reverse=False)
+        lo, any_l = walk(act_l, wl, passes(pass_low), reverse=True)
     cost_dp = hi + lo + (
         any_h.astype(jnp.float32) + any_l.astype(jnp.float32)
     ) * float(overhead)

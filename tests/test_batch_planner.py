@@ -3,7 +3,8 @@
 The load-bearing property is **bit-identity**: every plan the batched
 planner returns equals host ``plan()`` field for field — across algorithms
 (DPM / DPM-E), cost models (hops / weighted), every registered topology
-kind, and on degraded fabrics via the host fallback path. Plus: canonical
+kind, and on degraded meshes (detoured routes, segmented worms) on the
+device path. Plus: canonical
 dest-set interning shared with the plan cache, arena LRU hit/miss/eviction
 attribution mirroring ``plan_cache_info()``, and the consumer wiring
 (simulator bulk admission, dist schedule builder).
@@ -18,6 +19,7 @@ from hypothesis import strategies as st
 
 from repro.core import (
     BatchPlanner,
+    DisconnectedError,
     arena_clear,
     arena_info,
     batch_support,
@@ -26,12 +28,15 @@ from repro.core import (
     chiplet,
     faulty,
     grid,
+    label_chain_matrices,
+    label_chain_passes,
     mesh3d,
     plan,
     plan_cache_clear,
     plan_cache_info,
     planner_for,
     registered_topology_kinds,
+    router_failure,
     torus,
     torus3d,
 )
@@ -144,14 +149,18 @@ def test_batched_plan_bit_identical_property(seed):
 
 
 def test_degraded_fabric_falls_back_to_host():
+    """Broken links alone no longer send a fabric to the host; an
+    objective outside the gate (energy) still does, degraded or not, with
+    the same plans."""
     g = faulty(grid(4), (((0, 0), (1, 0)),))
-    sup = batch_support(g)
-    assert not sup.ok and "degraded" in sup.reason
-    bp = BatchPlanner(g, "DPM")
+    assert batch_support(g).ok
+    sup = batch_support(g, "DPM-E")
+    assert not sup.ok and "dyadic" in sup.reason
+    bp = BatchPlanner(g, "DPM-E")
     reqs = _requests(g, 6, seed=3)
     got = bp.plan_many(reqs)
     for (src, dests), pb in zip(reqs, got):
-        assert pb == plan("DPM", g, src, dests)
+        assert pb == plan("DPM-E", g, src, dests)
     info = bp.info()
     assert info.host_plans == len(reqs)
     assert info.batched_plans == 0 and info.dispatches == 0
@@ -368,6 +377,42 @@ def test_batch_padding_and_multi_chunk_batches():
         assert got[i] == plan("DPM", g, src, dests)
 
 
+@pytest.mark.parametrize("enabled", [True, False], ids=["on", "off"])
+@pytest.mark.parametrize("fail", [False, True], ids=["ok", "raises"])
+def test_collector_paused_while_decoding_then_restored(monkeypatch,
+                                                        enabled, fail):
+    """The decode runs with the cyclic collector off; afterwards it is in
+    the state the caller left it, also when the decode raises."""
+    import gc
+
+    g = grid(4)
+    bp = BatchPlanner(g, "DPM")
+    seen = []
+    real = BatchPlanner._decode
+
+    def spy(self, *a):
+        seen.append(gc.isenabled())
+        if fail:
+            raise RuntimeError("decode failed")
+        return real(self, *a)
+
+    monkeypatch.setattr(BatchPlanner, "_decode", spy)
+    was = gc.isenabled()
+    (gc.enable if enabled else gc.disable)()
+    try:
+        reqs = _requests(g, 6, seed=23)
+        if fail:
+            with pytest.raises(RuntimeError, match="decode failed"):
+                bp.plan_many(reqs)
+        else:
+            got = bp.plan_many(reqs)
+            assert got == [plan("DPM", g, s, d) for s, d in reqs]
+        assert gc.isenabled() is enabled
+    finally:
+        (gc.enable if was else gc.disable)()
+    assert seen and not any(seen)
+
+
 # ---------------------------------------------------------------------------
 # Destination-slot packing (kernels.dpm_cost.ops.dpm_plan_exact)
 # ---------------------------------------------------------------------------
@@ -407,7 +452,7 @@ def _merge(bp, reqs, k, bp_rows):
         dests[b, : len(ds)] = [g.idx(d) for d in ds]
     out = dpm_plan_exact(
         dests, sidx, t.memb_d, t.labels_d, t.dist_d, t.wuni_d, t.wh_d,
-        t.wl_d, np_=bp.np_, overhead=t.overhead,
+        t.wl_d, t.ph_d, t.pl_d, np_=bp.np_, overhead=t.overhead,
     )
     return [np.asarray(x) for x in out]
 
@@ -474,3 +519,153 @@ def test_source_among_dests_is_already_delivered(k):
             for src, dests in _fanout_requests(g, 3, k - 2, seed=k)]
     for (src, dests), pb in zip(reqs, bp.plan_many(reqs)):
         assert pb == plan("DPM", g, src, dests)
+
+
+# ---------------------------------------------------------------------------
+# Degraded meshes on the device path
+# ---------------------------------------------------------------------------
+def _connected_faults(g, count, seed):
+    """``count`` distinct links drawn uniformly from ``Random(seed)``,
+    each draw kept only if the mesh stays connected."""
+    from repro.core.routefn import components
+
+    rng = random.Random(seed)
+    links = sorted({tuple(sorted((u, v)))
+                    for u in g.nodes() for v in g.neighbors(*u)})
+    chosen: list = []
+    while len(chosen) < count:
+        cand = rng.choice(links)
+        if cand not in chosen and not components(
+                faulty(g, chosen + [cand])).any():
+            chosen.append(cand)
+    return chosen
+
+
+# a connected 4x4 mesh keeps 15 of its 24 links, so it holds at most 9
+# broken; (n, broken links) or (n, "router") for a failed router at (2, 3)
+DEGRADED = [(4, 1), (4, 4), (6, 1), (6, 4), (6, 12), (6, 20), (8, 1),
+            (8, 4), (8, 12), (8, 20), (8, "router")]
+
+
+def _degraded(n, faults):
+    g = grid(n)
+    if faults == "router":
+        return faulty(g, router_failure(g, (2, 3))), [(2, 3)]
+    return faulty(g, _connected_faults(g, faults, seed=100 * n + faults)), []
+
+
+@pytest.mark.parametrize("n,faults", DEGRADED,
+                         ids=[f"{n}x{n}-{f}" for n, f in DEGRADED])
+def test_degraded_mesh_plans_on_device_bit_identical(n, faults):
+    """Seeded connected fault sets (and one failed router, kept out of
+    the requests): every ``bulk_plan`` plan equals host ``plan()`` worm
+    for worm, all planned on the device."""
+    g, dead = _degraded(n, faults)
+    assert batch_support(g).ok
+    nodes = [u for u in g.nodes() if u not in dead]
+    rng = random.Random(n * 31 + len(dead))
+    reqs = []
+    for _ in range(48):
+        src = rng.choice(nodes)
+        k = rng.randint(1, min(16, len(nodes) - 1))
+        reqs.append((src, sorted(rng.sample(
+            [u for u in nodes if u != src], k))))
+    got = bulk_plan(g, reqs)
+    for (src, dests), pb in zip(reqs, got):
+        assert pb == plan("DPM", g, src, dests)
+    info = planner_for(g, "DPM").info()
+    assert info.host_plans == 0 and info.batched_plans == len(reqs)
+
+
+def test_unreachable_destination_raises_through_both_paths():
+    g = grid(6)
+    g = faulty(g, router_failure(g, (3, 3)))
+    for src, dests in [((0, 0), [(3, 3), (5, 5)]), ((3, 3), [(0, 0)])]:
+        with pytest.raises(DisconnectedError):
+            plan("DPM", g, src, dests)
+        with pytest.raises(DisconnectedError):
+            bulk_plan(g, [((1, 1), [(2, 2)]), (src, dests)])
+    # the planner stays usable for the nodes that remain
+    (pb,) = bulk_plan(g, [((0, 0), [(5, 5), (1, 4)])])
+    assert pb == plan("DPM", g, (0, 0), [(5, 5), (1, 4)])
+
+
+@pytest.mark.parametrize("n,faults", [(6, 12), (8, "router")])
+def test_degraded_chain_matrices_match_label_walks(n, faults):
+    """Every pairwise chain price, and every node a label route passes
+    past its target's label, is what a walk of ``provider.label_step``
+    gives."""
+    from repro.core.routefn import components, provider_for
+
+    g, _ = _degraded(n, faults)
+    wh, wl = label_chain_matrices(g)
+    ph, pl = label_chain_passes(g)
+    comp, step = components(g), provider_for(g).label_step
+    passed_some = False
+    for u in g.nodes():
+        for v in g.nodes():
+            iu, iv = g.idx(u), g.idx(v)
+            if u == v or comp[iu] != comp[iv]:
+                continue
+            high = g.label(*v) > g.label(*u)
+            walk = [u]
+            while walk[-1] != v:
+                walk.append(step(g, walk[-1], v, high))
+            assert (wh if high else wl)[iu, iv] == len(walk) - 1
+            lv = g.label(*v)
+            beyond = {g.idx(w) for w in walk
+                      if (g.label(*w) > lv if high else g.label(*w) < lv)}
+            words = (ph if high else pl)[iu, iv].view("uint32")
+            got = {32 * k + b for k, x in enumerate(words) for b in range(32)
+                   if int(x) >> b & 1}
+            assert got == beyond, (u, v, high)
+            passed_some |= bool(beyond)
+    assert passed_some
+    assert label_chain_passes(grid(n)) is None
+
+
+@pytest.mark.parametrize("n,faults", [(8, 12), (8, "router")])
+def test_degraded_device_candidates_match_definitions(n, faults):
+    """The device tables and the merge's per-candidate outputs follow the
+    host's Definitions 1-2 on a degraded mesh: wedge membership,
+    provider-route distances and prices, and for every candidate its
+    representative, MU/DP mode and cost."""
+    import numpy as np
+
+    from repro.core import candidate_cost, route_cost_matrices
+    from repro.core.partition import basic_partitions
+    from repro.core.routefn import components, provider_for
+
+    g, dead = _degraded(n, faults)
+    dist, w_uni, _ = route_cost_matrices(g)
+    comp = components(g)
+    for u in g.nodes():
+        for v in g.nodes():
+            iu, iv = g.idx(u), g.idx(v)
+            if u == v:
+                continue
+            if comp[iu] != comp[iv]:
+                assert dist[iu, iv] == -1 and np.isinf(w_uni[iu, iv])
+                continue
+            assert dist[iu, iv] == g.distance(u, v)
+            assert w_uni[iu, iv] == len(provider_for(g).unicast(g, u, v)) - 1
+    bp = BatchPlanner(g, "DPM")
+    memb = bp._tables().memb_rows
+    rng = random.Random(n)
+    nodes = [u for u in g.nodes() if u not in dead]
+    reqs = [(src, sorted(rng.sample([u for u in nodes if u != src], 12)))
+            for src in rng.sample(nodes, 16)]
+    _, _, reps, modes, costs = _merge(bp, reqs, bpm.MIN_SLOTS, 16)
+    for b, (src, dests) in enumerate(reqs):
+        parts = basic_partitions(src, dests, g)
+        for d in dests:
+            assert parts[memb[g.idx(src)][g.idx(d)]].count(d) == 1
+        for ci, ids in enumerate(bp._cands):
+            union = [d for i in ids for d in parts[i]]
+            if not union:
+                assert reps[b, ci] == -1
+                continue
+            cc = candidate_cost(g, src, ids, union)
+            assert reps[b, ci] == g.idx(cc.rep)
+            assert bool(modes[b, ci]) == (cc.mode == "MU")
+            assert costs[b, ci] == cc.cost(True)

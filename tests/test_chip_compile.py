@@ -92,6 +92,32 @@ def test_dpm_plan_exact_compiles_for_v5e(side, k, one_chip,
     _fits(compiled)
 
 
+@pytest.mark.parametrize("side", [8, 32])
+def test_degraded_dpm_plan_exact_compiles_for_v5e(side, one_chip,
+                                                  no_persistent_cache):
+    """The dispatch on a degraded fabric: the chain-pass bitmasks
+    (``label_chain_passes``) added, at the paper's fanouts."""
+    from repro.core.batch_planner import DISPATCH_CHUNK, MIN_SLOTS
+    from repro.kernels.dpm_cost.ops import dpm_plan_exact
+
+    B, NN, W = DISPATCH_CHUNK, side * side, -(-side * side // 32)
+    i32, f32 = jnp.int32, jnp.float32
+    args = (
+        _spec((B, MIN_SLOTS), i32, one_chip),
+        _spec((B,), i32, one_chip),
+        _spec((NN, NN), i32, one_chip),
+        _spec((NN,), i32, one_chip),
+        _spec((NN, NN), i32, one_chip),
+        _spec((NN, NN), f32, one_chip),
+        _spec((NN, NN), f32, one_chip),
+        _spec((NN, NN), f32, one_chip),
+        _spec((NN, NN, W), i32, one_chip),  # HIGH chain-pass bitmasks
+        _spec((NN, NN, W), i32, one_chip),  # LOW chain-pass bitmasks
+    )
+    compiled = dpm_plan_exact.lower(*args, np_=8, overhead=0.0).compile()
+    _fits(compiled)
+
+
 def test_xsim_ref_backend_compiles_for_v5e_16x16(one_chip,
                                                  no_persistent_cache):
     """xsim's batched ``lax.scan`` engine as ``xsimulate`` builds it for a

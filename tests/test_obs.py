@@ -25,6 +25,8 @@ if str(ROOT) not in sys.path:  # the trace reduction lives in bench/
 
 TIME_FIELDS = ("plan_s", "lookup_s", "dispatch_s", "sync_s", "decode_s",
                "host_plan_s")
+# a fault outside the 4x4's source rows: degraded plans are segmented
+DEGRADED = faulty(grid(4), (((0, 0), (1, 0)),))
 
 
 @pytest.fixture()
@@ -41,8 +43,8 @@ def _requests(k: int, shift: int = 0):
              [((i + 1 + shift) % 4, 3), (3, (i + 2) % 4)]) for i in range(k)]
 
 
-def _serve(topo, reqs):
-    with PlanServer(topo, "DPM", max_batch=8, max_wait_s=0.002) as ps:
+def _serve(topo, reqs, algo="DPM"):
+    with PlanServer(topo, algo, max_batch=8, max_wait_s=0.002) as ps:
         for f in [ps.submit(src, dests) for src, dests in reqs]:
             f.result(timeout=60)
     return ps
@@ -50,13 +52,14 @@ def _serve(topo, reqs):
 
 @pytest.mark.parametrize("healthy", [True, False], ids=["device", "host"])
 def test_time_counters_fill_and_stay_ordered(healthy, _fresh_arena):
-    topo = grid(4) if healthy else faulty(grid(4), (((0, 0), (1, 0)),))
-    ps = _serve(topo, _requests(12))
+    """The host case: the energy objective, outside the device gate."""
+    algo = "DPM" if healthy else "DPM-E"
+    ps = _serve(grid(4), _requests(12), algo)
     st = ps.stats
     assert st["requests"] == 12
     assert 0.0 < st["queue_wait_max_s"] <= st["queue_wait_s"]
     first = ps.info()
-    second = _serve(topo, _requests(12, shift=1)).info()
+    second = _serve(grid(4), _requests(12, shift=1), algo).info()
     for info in (first, second):
         assert all(getattr(info, f) >= 0.0 for f in TIME_FIELDS)
         parts = (info.lookup_s + info.dispatch_s + info.sync_s
@@ -110,14 +113,14 @@ def test_profiler_trace_holds_the_program_spans(python_tracer, tmp_path,
                                                  _fresh_arena):
     """The spans are host trace events: they need no Python tracer, whose
     per-call recording slows host planning."""
-    healthy, degraded = grid(4), faulty(grid(4), (((0, 0), (1, 0)),))
     obs.install_gc_clock()
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = python_tracer
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
-        _serve(healthy, _requests(12))
-        _serve(degraded, _requests(4))
+        _serve(grid(4), _requests(12))
+        _serve(DEGRADED, _requests(4))
+        _serve(grid(4), _requests(4), "DPM-E")
         gc.collect()
     finally:
         jax.profiler.stop_trace()
@@ -127,6 +130,7 @@ def test_profiler_trace_holds_the_program_spans(python_tracer, tmp_path,
         "repro.planserve.resolve", "repro.planner.lookup",
         "repro.planner.dispatch", "repro.planner.sync",
         "repro.planner.decode", "repro.planner.host_plan", "repro.gc",
+        "repro.planner.segment", "repro.planner.tables",
     }
 
 
@@ -144,6 +148,32 @@ def test_idle_gap_takes_the_innermost_program_span():
     assert r.idle_gaps(1) == [["repro.gc", 100e-9]]
 
 
+def test_segment_counters_count_what_segmentation_changed(_fresh_arena):
+    """On a degraded fabric the decode segments device plans: the
+    counters hold the plans segmentation changed, the worms it added and
+    its seconds, a part of the decode's."""
+    from repro.core import (bulk_plan, plan_dpm, planner_for,
+                            segment_plan_for_faults)
+
+    reqs = _requests(12) + _requests(12, shift=1)
+    plans = bulk_plan(DEGRADED, reqs)
+    raw = {(src, tuple(sorted(d))): plan_dpm(DEGRADED, src, d)
+           for src, d in reqs}
+    seg = {k: segment_plan_for_faults(p, DEGRADED) for k, p in raw.items()}
+    info = planner_for(DEGRADED, "DPM").info()
+    assert info.batched_plans == len(raw)
+    assert 0 < info.segmented_plans == sum(
+        seg[k] is not p for k, p in raw.items())
+    assert info.relay_worms == sum(
+        len(seg[k].paths) - len(p.paths) for k, p in raw.items())
+    assert 0.0 < info.segment_s <= info.decode_s
+    assert [p.paths for p in plans] == [
+        seg[(src, tuple(sorted(d)))].paths for src, d in reqs]
+    bulk_plan(grid(4), reqs)
+    h = planner_for(grid(4), "DPM").info()
+    assert (h.segment_s, h.segmented_plans, h.relay_worms) == (0.0, 0, 0)
+
+
 def _hlo_module_name(lowered) -> str:
     return re.match(r"HloModule (\S+?),", lowered.as_text(dialect="hlo"))[1]
 
@@ -158,6 +188,20 @@ def test_merge_module_keeps_the_name_trace_readers_match(k):
     shapes = [((B, k), i32), ((B,), i32), ((NN, NN), i32), ((NN,), i32),
               ((NN, NN), i32), ((NN, NN), f32), ((NN, NN), f32),
               ((NN, NN), f32)]
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
+    lowered = dpm_plan_exact.lower(*args, np_=8, overhead=0.0)
+    assert "dpm_plan_exact" in _hlo_module_name(lowered)
+
+
+def test_degraded_merge_keeps_the_module_name():
+    """With the chain-pass tables of a degraded fabric, too."""
+    from repro.kernels.dpm_cost.ops import dpm_plan_exact
+
+    B, NN, k = 4, 64, 16
+    i32, f32 = jnp.int32, jnp.float32
+    shapes = [((B, k), i32), ((B,), i32), ((NN, NN), i32), ((NN,), i32),
+              ((NN, NN), i32), ((NN, NN), f32), ((NN, NN), f32),
+              ((NN, NN), f32), ((NN, NN, 2), i32), ((NN, NN, 2), i32)]
     args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
     lowered = dpm_plan_exact.lower(*args, np_=8, overhead=0.0)
     assert "dpm_plan_exact" in _hlo_module_name(lowered)
