@@ -14,9 +14,12 @@ The correctness contract is **bit-identity with the host planner**: every
 decoded plan equals ``plan(algo, topo, src, dests, cost_model=...)`` field
 for field. Three things make that hold:
 
-* the decode step rebuilds paths through the exact host construction code
-  (``planner._emit_dpm_partition``) from the device-chosen partitions,
-  representatives, modes, and pick order;
+* the decode step reimplements the host emitter
+  (``planner._emit_dpm_partition``) with array operations over a whole
+  dispatched chunk: from the device-chosen partitions, representatives,
+  modes and pick order it lays out every worm's route segments, expands
+  them from a pool of memoized routes and takes deliveries as first
+  visits along the expanded worm, as ``_deliveries_on`` does;
 * a label-chain decomposition prices C_p exactly on device: a label-ordered
   chain is the concatenation of pairwise label routes between consecutive
   members, skipping a member an earlier route already passed through. On a
@@ -62,7 +65,6 @@ from .partition import candidate_ids_for, wedge_patterns
 from .planner import (
     MulticastPlan,
     PacketPath,
-    _emit_dpm_partition,
     canonical_dests,
     plan,
     plan_dpm,
@@ -70,7 +72,7 @@ from .planner import (
     segment_plan_for_faults,
 )
 from .routefn import components, provider_for, route_cost_matrices
-from .routing import label_route, xy_route
+from .routing import label_route
 
 # Dense lowering is O(NN^2) host work (once per topology/model, cached);
 # cap it so a misconfigured huge fabric degrades to host planning instead
@@ -143,7 +145,9 @@ class ArenaInfo(NamedTuple):
     label-monotone worms (degraded and BFS-routed ones), ``segment_s`` is
     the part of ``decode_s`` spent segmenting device plans,
     ``segmented_plans`` counts the plans segmentation changed and
-    ``relay_worms`` the worms it added."""
+    ``relay_worms`` the worms it added. ``array_decoded`` counts the
+    plans decoded by the chunk array decode (``BatchPlanner._decode_chunk``),
+    every plan planned on the device."""
 
     hits: int
     misses: int
@@ -162,6 +166,7 @@ class ArenaInfo(NamedTuple):
     segment_s: float
     segmented_plans: int
     relay_worms: int
+    array_decoded: int
 
 
 class ArenaCacheInfo(NamedTuple):
@@ -353,7 +358,10 @@ def batch_support(topo: MeshGrid, algo="DPM", cost_model=None) -> _Support:
 # The batched planner + arena
 # ---------------------------------------------------------------------------
 class _Tables(NamedTuple):
-    memb_rows: list  # membership table as nested python lists (decode)
+    memb: np.ndarray  # int8 membership table (decode: each member's wedge)
+    labels: np.ndarray  # snake labels (decode: chain order and sides)
+    ph: np.ndarray | None  # label_chain_passes (decode: chain skips)
+    pl: np.ndarray | None
     memb_d: object  # device copies (jax arrays)
     labels_d: object
     dist_d: object
@@ -363,6 +371,65 @@ class _Tables(NamedTuple):
     ph_d: object  # label_chain_passes on the device, or None
     pl_d: object
     overhead: float
+
+
+# Route kinds in a decoded worm: the unicast route, or the HIGH / LOW
+# label route of a dual-path chain.
+_UNI, _HIGH, _LOW = 0, 1, 2
+
+
+class _RoutePool:
+    """Every route the decode has expanded, as node indices in one flat
+    array: route ``r = kind * NN^2 + a * NN + b`` (``_UNI`` the unicast
+    route a -> b, ``_HIGH`` / ``_LOW`` the label route) is
+    ``nodes[s : s + n]`` for ``s, n = span[r]``, both endpoints included
+    (``s`` -1 until the route is needed). Filled lazily, each route the
+    first time a decode needs it, through the topology's route provider;
+    bounded by the 3 NN^2 node pairs."""
+
+    def __init__(self, topo: MeshGrid):
+        NN = topo.num_nodes
+        self.topo = topo
+        self.span = np.full((3 * NN * NN, 2), -1, np.int32)
+        self.nodes = np.zeros(1 << 12, np.int32)
+        self.size = 0
+
+    def lookup(self, rid: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+        sp = self.span[rid]
+        missing = sp[:, 0] < 0
+        if missing.any():
+            self._fill(np.unique(rid[missing]))
+            sp = self.span[rid]
+        return sp[:, 0], sp[:, 1]
+
+    def _fill(self, rids: np.ndarray) -> None:
+        g = self.topo
+        NN = g.num_nodes
+        node = g.from_idx
+        unicast = provider_for(g).unicast
+        routes = []
+        for r in rids.tolist():
+            kind, pair = divmod(r, NN * NN)
+            u, v = node(pair // NN), node(pair % NN)
+            hops = (unicast(g, u, v) if kind == _UNI
+                    else label_route(g, u, v, kind == _HIGH))
+            routes.append([g.idx(c) for c in hops])
+        lens = np.array([len(x) for x in routes], np.int32)
+        end = self.size + int(lens.sum())
+        if end > len(self.nodes):
+            grown = np.zeros(max(end, 2 * len(self.nodes)), np.int32)
+            grown[: self.size] = self.nodes[: self.size]
+            self.nodes = grown
+        self.nodes[self.size : end] = np.concatenate(routes)
+        self.span[rids, 0] = self.size + np.cumsum(lens) - lens
+        self.span[rids, 1] = lens
+        self.size = end
+
+
+def _rank_in_runs(keys: np.ndarray) -> np.ndarray:
+    """Position of each element of an ascending key array within its run
+    of equal keys."""
+    return np.arange(len(keys)) - np.searchsorted(keys, keys)
 
 
 class BatchPlanner:
@@ -401,13 +468,19 @@ class BatchPlanner:
         self._arena: "OrderedDict[tuple, MulticastPlan]" = OrderedDict()
         self._lock = threading.Lock()
         self._tables_cached: _Tables | None = None
-        # Route memos for decode: (a, b) -> unicast hops, (a, b, high) ->
-        # label-route segment past a. Naturally bounded by NN^2 (resp.
-        # 2*NN^2) keys — node-pair tables, same order as the dense price
-        # matrices this planner already holds.
-        self._uni_memo: dict[tuple, tuple] = {}
-        self._seg_memo: dict[tuple, tuple] = {}
-        self._labmap: dict[Coord, int] = {}
+        self._routes: _RoutePool | None = None  # made by the first decode
+        # decode constants: node index -> Coord, and per candidate the
+        # wedges it holds and each wedge's place in its union
+        self._coords = np.empty(topo.num_nodes, object)
+        for i, c in enumerate(topo.nodes()):
+            self._coords[i] = c
+        width = max(map(len, self._cands))
+        self._cand_wedges = np.array(  # padded by repeating the first
+            [ids + ids[:1] * (width - len(ids)) for ids in self._cands])
+        self._wedge_pos = np.zeros((len(self._cands), self.np_), np.int64)
+        for ci, ids in enumerate(self._cands):
+            self._wedge_pos[ci, list(ids)] = range(len(ids))
+        self._member_at = np.zeros(0, np.int32)  # decode scratch, all -1
         self._hits = 0
         self._misses = 0
         self._evictions = 0
@@ -417,7 +490,7 @@ class BatchPlanner:
         self._plan_s = self._lookup_s = self._dispatch_s = 0.0
         self._sync_s = self._decode_s = self._host_plan_s = 0.0
         self._segment_s = 0.0
-        self._segmented = self._relays = 0
+        self._segmented = self._relays = self._array_decoded = 0
         install_gc_clock()
 
     # ------------------------------------------------------------- public
@@ -440,7 +513,7 @@ class BatchPlanner:
             self._evictions, self._batched, self._host, self._dispatches,
             self._plan_s, self._lookup_s, self._dispatch_s, self._sync_s,
             self._decode_s, self._host_plan_s, self._segment_s,
-            self._segmented, self._relays,
+            self._segmented, self._relays, self._array_decoded,
         )
 
     def clear(self) -> None:
@@ -520,7 +593,10 @@ class BatchPlanner:
                 labels = snake_labels(self.topo)
                 memb = membership_table(self.topo)
                 self._tables_cached = _Tables(
-                    memb.tolist(),
+                    memb.astype(np.int8),  # wedge ids < 27: a quarter the bytes
+                    labels,
+                    ph,
+                    pl,
                     jnp.asarray(memb),
                     jnp.asarray(labels),
                     jnp.asarray(dist),
@@ -538,7 +614,8 @@ class BatchPlanner:
         padded to a power of two, each packed into ``k`` destination slots.
         Returns the device arrays *without* synchronizing — JAX dispatch is
         asynchronous, so the caller can keep issuing chunks (and decoding
-        earlier ones) while XLA computes this one in its own threadpool."""
+        earlier ones) while XLA computes this one in its own threadpool —
+        with the packed ``(dests, sidx)`` the decode reads."""
         import jax.numpy as jnp
 
         from ..kernels.dpm_cost.ops import dpm_plan_exact
@@ -551,7 +628,7 @@ class BatchPlanner:
         for b, (src, ds) in enumerate(keys):
             sidx[b] = idx(src)
             dests[b, : len(ds)] = [idx(d) for d in ds]
-        return dpm_plan_exact(
+        out = dpm_plan_exact(
             jnp.asarray(dests),
             jnp.asarray(sidx),
             t.memb_d,
@@ -565,6 +642,7 @@ class BatchPlanner:
             np_=self.np_,
             overhead=t.overhead,
         )
+        return out, dests, sidx
 
     def _plan_batch(self, keys: list[tuple]) -> list[MulticastPlan]:
         # Issue every chunk's device work first (async dispatch), then
@@ -593,20 +671,13 @@ class BatchPlanner:
         self._dispatches += len(chunks)
         plans: list[MulticastPlan] = []
         with _collector_paused():  # the decoded plans all survive
-            for ck, out in zip(chunks, outs):
-                # one bulk device->host sync + python-list conversion per chunk
-                # (per-element numpy scalar indexing in decode costs more than
-                # the whole transfer)
+            for ck, (out, dests, sidx) in zip(chunks, outs):
+                # one bulk device->host sync per chunk
                 with span("repro.planner.sync"):
                     host = [np.asarray(x) for x in out[:4]]
                 t2 = time.perf_counter()
                 with span("repro.planner.decode"):
-                    chosen, order, reps, modes = (x.tolist() for x in host)
-                    got = [
-                        self._decode(src, dests, chosen[b], order[b], reps[b],
-                                     modes[b])
-                        for b, (src, dests) in enumerate(ck)
-                    ]
+                    got = self._decode_chunk(ck, *host, dests, sidx)
                     plans.extend(self._segment(got) if self._segments else got)
                 t3 = time.perf_counter()
                 self._sync_s += t2 - t1
@@ -627,83 +698,211 @@ class BatchPlanner:
                 self._relays += len(q.paths) - len(p.paths)
         return out
 
-    def _uni(self, a: Coord, b: Coord) -> list[Coord]:
-        """Memoized ``xy_route`` (fresh list per call — plans own their
-        hop lists)."""
-        r = self._uni_memo.get((a, b))
-        if r is None:
-            r = self._uni_memo[(a, b)] = tuple(xy_route(self.topo, a, b))
-        return list(r)
+    def _decode_chunk(self, keys, chosen, order, reps, modes, dests, sidx):
+        """One dispatched chunk's partition tensors -> its
+        ``MulticastPlan``s, by array operations over the whole chunk. This
+        reimplements the host emitter (``planner._emit_dpm_partition``,
+        which ``plan_dpm`` runs) step by step; ``tests/test_batch_planner.py``
+        pins the two equal, worm for worm:
 
-    def _chain(self, cur: Coord, dests, *, high: bool) -> list[Coord]:
-        """Memoized ``path_multicast`` equivalent: the label-ordered chain
-        is the concatenation of pairwise label routes between consecutive
-        label-sorted members, skipping a member an earlier route passed
-        through (delivered there) — the same decomposition
-        ``dpm_plan_exact`` prices C_p with. Only a detour passes a later
-        member, so healthy fabrics skip the check."""
-        g = self.topo
-        pending = [d for d in dests if d != cur]
-        if not pending:
-            return [cur]
-        if not self._labmap:
-            self._labmap.update((u, g.label(*u)) for u in g.nodes())
-        pending.sort(key=self._labmap.__getitem__, reverse=not high)
-        path = [cur]
-        seen = set() if self._tables().ph_d is not None else None
-        for t in pending:
-            if seen is not None and t in seen:
-                continue
-            key = (path[-1], t, high)
-            seg = self._seg_memo.get(key)
-            if seg is None:
-                seg = self._seg_memo[key] = tuple(
-                    label_route(g, path[-1], t, high)[1:]
-                )
-            path.extend(seg)
-            if seen is not None:
-                seen.update(seg)
-        return path
+        * a destination's final partition is the picked candidate whose
+          wedges hold it (the source is in no wedge: already delivered);
+        * partitions emit by greedy pick round, then leftover singles by
+          candidate index (``NO_ORDER`` sorts after every round); members
+          keep the union order, wedge by wedge in the candidate, each
+          wedge in destination order;
+        * a DP partition of two or more members emits the worm S -> R
+          continued by the chain into its larger label side (HIGH on a
+          tie), then a sibling re-injected at R with the other side's
+          chain; a chain visits its side in label order, one label route
+          to each member, skipping a member an earlier route of the chain
+          passed (only on a degraded fabric, ``_chain_visits``);
+        * any other partition emits the worm S -> R, then a unicast worm
+          from R to each member the head did not pass, in union order;
+        * a worm is its routes joined end to end (``_RoutePool``), and
+          delivers each member it serves at its first visit, as
+          ``_deliveries_on`` does.
 
-    def _decode(self, src, dests, chosen, order, reps, modes) -> MulticastPlan:
-        """Partition tensors -> MulticastPlan, in host emission order:
-        merge winners by greedy pick round, then leftover singles by
-        ascending candidate index (NO_ORDER sorts them after every round).
-        Wedge assignment comes from the cached membership table (the same
-        rows the device merge partitioned with), and paths are rebuilt
-        through ``_emit_dpm_partition`` with memoized route primitives."""
-        g = self.topo
-        cands = self._cands
-        row = self._tables().memb_rows[g.idx(src)]
-        parts: list[list[Coord]] = [[] for _ in range(self.np_)]
-        for d in dests:
-            w = row[g.idx(d)]
-            if w >= 0:  # the source itself is in no wedge: delivered
-                parts[w].append(d)
-        picked = sorted(
-            (ci for ci in range(len(cands)) if chosen[ci]),
-            key=lambda ci: (order[ci], ci),
-        )
-        p = MulticastPlan(self._algo.name, src, list(dests))
-        for ci in picked:
-            union: list[Coord] = []
-            for i in cands[ci]:
-                union.extend(parts[i])
-            if not union:
-                continue
-            rep = g.from_idx(reps[ci])
-            if len(union) == 1:
-                # singleton partition: rep is the lone member, the emission
-                # is exactly the S->R head delivering at R (both modes) —
-                # skip the general machinery
-                p.paths.append(PacketPath(self._uni(src, rep), [rep]))
-                continue
-            mode = "MU" if modes[ci] else "DP"
-            _emit_dpm_partition(
-                p, g, src, union, rep, mode,
-                unicast=self._uni, chain=self._chain,
-            )
-        return p
+        Python then touches each plan once, to slice its worms out of the
+        chunk's flat hop and delivery lists (``_decode``)."""
+        B = len(keys)
+        t = self._tables()
+        NN = self.topo.num_nodes
+        NC = chosen.shape[1]
+        self._array_decoded += B
+        # partitions in emission order: the picked nonempty candidates by
+        # (plan, pick round, candidate index)
+        ub, uc = np.nonzero(chosen[:B] & (reps[:B] >= 0))
+        o = np.argsort((ub << 40) + order[ub, uc].astype(np.int64) * NC + uc)
+        pb, pci = ub[o], uc[o]
+        P = len(pb)
+        if P == 0:  # nothing to deliver but the source itself
+            return [self._decode(src, ds, []) for src, ds in keys]
+        prep = reps[pb, pci].astype(np.int64)
+        # members: the destination slots in a wedge, each in the picked
+        # candidate that holds its wedge (picked candidates are disjoint)
+        held = np.zeros((B, self.np_), np.int64)
+        held[pb[:, None], self._cand_wedges[pci]] = np.arange(P)[:, None]
+        dests = dests[:B]
+        wedge = t.memb[sidx[:B, None], np.maximum(dests, 0)]
+        mb, mk = np.nonzero((dests >= 0) & (wedge >= 0))
+        mv = dests[mb, mk].astype(np.int64)
+        mw = wedge[mb, mk]
+        mg = held[mb, mw]
+        lab = t.labels
+        side = np.sign(lab[mv] - lab[prep[mg]])  # 0 at the representative
+        cnt = np.bincount(mg, minlength=P)
+        nh = np.bincount(mg, side > 0, minlength=P)
+        nl = np.bincount(mg, side < 0, minlength=P)
+        mu = modes[pb, pci] | (cnt < 2)
+        first = np.where(nh >= nl, 1, -1)
+        sibling = ~mu & (np.where(first > 0, nl, nh) > 0)
+        # worms: a DP partition's head and sibling; an MU partition's head
+        # and a unicast worm per other member (dropped below where the
+        # head passes the member)
+        nworm = np.where(mu, cnt, 1 + sibling)
+        wstart = np.cumsum(nworm) - nworm
+        W = int(nworm.sum())
+        wg = np.repeat(np.arange(P), nworm)
+        wsub = np.arange(W) - wstart[wg]
+        wside = np.where((wsub > 0) & ~mu[wg], -first[wg], 0)
+        wmember = np.full(W, -1, np.int64)
+        kid = np.nonzero(mu[mg] & (side != 0))[0]
+        kid = kid[np.argsort(
+            (mg[kid] * self.np_ + self._wedge_pos[pci[mg[kid]], mw[kid]])
+            * dests.shape[1] + mk[kid])]
+        kid_worm = wstart[mg[kid]] + 1 + _rank_in_runs(mg[kid])
+        wmember[kid_worm] = kid
+        # chains: DP members off the representative, by (partition, side,
+        # label in the side's direction)
+        cm = np.nonzero(~mu[mg] & (side != 0))[0]
+        up = side[cm] > 0
+        cm = cm[np.argsort((4 * mg[cm] + 2 * up) * NN
+                           + np.where(up, lab[mv[cm]], -lab[mv[cm]]))]
+        up = side[cm] > 0
+        chain = 2 * mg[cm] + up
+        if t.ph is not None and len(cm):
+            go = self._chain_visits(chain, mv[cm], up, prep[mg[cm]])
+            cm, chain, up = cm[go], chain[go], up[go]
+        cpos = _rank_in_runs(chain)
+        cv = mv[cm]
+        on_head = side[cm] == first[mg[cm]]
+        # route segments: (worm, place in worm, from, to, kind)
+        seg = [np.concatenate(x) for x in zip(
+            (wstart, np.zeros(P, np.int64), sidx[pb].astype(np.int64), prep,
+             np.full(P, _UNI)),
+            (wstart[mg[cm]] + ~on_head, cpos + on_head,
+             np.where(cpos > 0, np.concatenate((cv[:1], cv[:-1])),
+                      prep[mg[cm]]), cv,
+             np.where(up, _HIGH, _LOW)),
+            (kid_worm, np.zeros(len(kid), np.int64), prep[mg[kid]], mv[kid],
+             np.full(len(kid), _UNI)),
+        )]
+        nseg = np.bincount(seg[0], minlength=W)
+        sstart = np.cumsum(nseg) - nseg
+        at = sstart[seg[0]] + seg[1]
+        place, src_n, dst_n, kind = (np.empty_like(x) for x in seg[1:])
+        for out_, x in zip((place, src_n, dst_n, kind), seg[1:]):
+            out_[at] = x
+        # expand: each worm's first route whole, later ones past their
+        # first node (the previous route's last)
+        if self._routes is None:
+            self._routes = _RoutePool(self.topo)
+        st, ln = self._routes.lookup((kind * NN + src_n) * NN + dst_n)
+        later = place > 0
+        st, ln = st + later, ln - later
+        off = np.cumsum(ln) - ln
+        hops = self._routes.nodes[np.repeat(st - off, ln)
+                                  + np.arange(int(ln.sum()))]
+        wlen = np.add.reduceat(ln, sstart)
+        woff = np.cumsum(wlen) - wlen
+        # deliveries: first visits of the members each worm serves — the
+        # head all of its partition, a sibling its side, a unicast worm
+        # its member. ``_member_at`` maps (plan, node) to the member.
+        wb = pb[wg]
+        cell = mb * NN + mv
+        if len(self._member_at) < B * NN:
+            self._member_at = np.full(B * NN, -1, np.int32)
+        self._member_at[cell] = np.arange(len(mv))
+        m = self._member_at[np.repeat(wb * NN, wlen) + hops]
+        self._member_at[cell] = -1
+        hp = np.nonzero(m >= 0)[0]
+        m = m[hp]
+        hw = np.searchsorted(woff, hp, side="right") - 1
+        ws, wm = wside[hw], wmember[hw]
+        ok = ((mg[m] == wg[hw]) & ((ws == 0) | (side[m] == ws))
+              & ((wm < 0) | (m == wm)))
+        hp, hw, m = hp[ok], hw[ok], m[ok]
+        _, firsts = np.unique(hw * NN + hops[hp], return_index=True)
+        firsts.sort()
+        dpos, dw = hp[firsts], hw[firsts]
+        dcnt = np.bincount(dw, minlength=W)
+        doff = np.cumsum(dcnt) - dcnt
+        # an MU partition's unicast worms go only to the members its head
+        # did not pass
+        head = (wsub[hw] == 0) & mu[wg[hw]]
+        passed = np.zeros(len(mv), bool)
+        passed[m[head]] = True
+        keep = wmember < 0
+        keep[kid_worm] = ~passed[kid]
+        kept = np.nonzero(keep)[0]
+        per_plan = np.bincount(wb[kept], minlength=B)
+        local = np.cumsum(keep) - 1 - (np.cumsum(per_plan) - per_plan)[wb]
+        parent = np.where(wsub > 0, local[wstart[wg]], -1)[kept]
+        # Python: one slice of the flat hop and delivery lists per worm
+        H = self._coords[hops].tolist()
+        D = self._coords[hops[dpos]].tolist()
+        worms = [
+            PacketPath(H[a:b], D[c:d], None if p < 0 else p)
+            for a, b, c, d, p in zip(
+                woff[kept].tolist(), (woff + wlen)[kept].tolist(),
+                doff[kept].tolist(), (doff + dcnt)[kept].tolist(),
+                parent.tolist())
+        ]
+        out, lo = [], 0
+        for (src, ds), hi in zip(keys, np.cumsum(per_plan).tolist()):
+            out.append(self._decode(src, ds, worms[lo:hi]))
+            lo = hi
+        return out
+
+    def _chain_visits(self, chain, node, high, rep) -> np.ndarray:
+        """Which members of the label-sorted chains (``chain`` ids, runs in
+        visit order) the chain routes to: a member an earlier route of
+        its chain passed is delivered there and skipped, as
+        ``routing.path_multicast`` skips it. ``label_chain_passes`` holds
+        the nodes each pairwise route passes beyond its target; a loop
+        over the chain positions, each step over every chain at once."""
+        t = self._tables()
+        new = np.ones(len(chain), bool)
+        new[1:] = chain[1:] != chain[:-1]
+        cid = np.cumsum(new) - 1
+        pos = _rank_in_runs(chain)
+        C, L = int(cid[-1]) + 1, int(pos.max()) + 1
+        nodes = np.full((C, L), -1, np.int64)
+        nodes[cid, pos] = node
+        hi = np.zeros(C, bool)
+        hi[cid] = high
+        cur = np.zeros(C, np.int64)
+        cur[cid[new]] = rep[new]
+        passed = np.zeros((C, L), bool)
+        for j in range(L):
+            tj = nodes[:, j]
+            go = (tj >= 0) & ~passed[:, j]
+            r = np.nonzero(go)[0]
+            later = nodes[r, j + 1:]
+            if later.size:
+                a, b, w = cur[r, None], tj[r, None], np.maximum(later, 0)
+                word = np.where(hi[r, None], t.ph[a, b, w >> 5],
+                                t.pl[a, b, w >> 5])
+                passed[r, j + 1:] |= (((word >> (w & 31)) & 1) == 1) & (
+                    later >= 0)
+            cur = np.where(go, tj, cur)
+        return ~passed[cid, pos]
+
+    def _decode(self, src, dests, paths) -> MulticastPlan:
+        """One plan of a decoded chunk around its worms: the decode's only
+        per-plan step."""
+        return MulticastPlan(self._algo.name, src, list(dests), paths)
 
 
 # ---------------------------------------------------------------------------

@@ -152,29 +152,19 @@ def plan_nmp(g: MeshGrid, src: Coord, dests: list[Coord]) -> MulticastPlan:
 # --------------------------------------------------------------------------
 def _emit_dpm_partition(
     plan: MulticastPlan, g: MeshGrid, src: Coord, dests: list[Coord],
-    rep: Coord, mode: str, *, unicast=None, chain=None,
+    rep: Coord, mode: str,
 ) -> None:
     """Append one final partition's delivery paths to ``plan``.
 
     S --XY--> R head, then either the dual-path continuation (the chain
     continues into the larger label side; the other side is a sibling child
-    re-injected at R) or MU-mode child unicasts. Shared by the host
-    construction loop (``plan_dpm``) and the batched planner's decode step
-    (``core.batch_planner``) — device-planned partitions decode through the
-    exact code path host plans are built with, which is what makes the
-    bit-identical contract hold structurally rather than by coincidence.
-
-    ``unicast(a, b)`` / ``chain(a, group, high=...)`` override the route
-    primitives (defaults: ``xy_route`` / ``path_multicast``). The batched
-    decode passes memoized equivalents so repeated (src, rep) legs across a
-    batch don't re-walk routes hop by hop; the partition-to-paths structure
-    (DP split, larger-side-first, deliveries, parent links) stays here.
+    re-injected at R) or MU-mode child unicasts. This is the host emitter
+    ``plan_dpm`` runs. The batched planner does not call it: its chunk
+    decode (``core.batch_planner.BatchPlanner._decode_chunk``) reimplements
+    these rules with array operations over a whole dispatched chunk, and
+    ``tests/test_batch_planner.py`` pins the two equal, worm for worm.
     """
-    if unicast is None:
-        unicast = functools.partial(xy_route, g)
-    if chain is None:
-        chain = functools.partial(path_multicast, g)
-    head = unicast(src, rep)
+    head = xy_route(g, src, rep)
     rest = [d for d in dests if d != rep]
     if mode == "DP" and rest:
         lr = g.label(*rep)
@@ -183,13 +173,14 @@ def _emit_dpm_partition(
         # The chain continues into the *larger* side from the head packet;
         # the other side is a sibling packet re-injected at R.
         first, second = (d_h, d_l) if len(d_h) >= len(d_l) else (d_l, d_h)
-        tail = chain(rep, first, high=first is d_h) if first else [rep]
+        tail = (path_multicast(g, rep, first, high=first is d_h) if first
+                else [rep])
         full = head + tail[1:]
         deliver = _deliveries_on(full, set(dests))
         parent_idx = len(plan.paths)
         plan.paths.append(PacketPath(full, deliver))
         if second:
-            spath = chain(rep, second, high=second is d_h)
+            spath = path_multicast(g, rep, second, high=second is d_h)
             plan.paths.append(
                 PacketPath(
                     spath,
@@ -204,7 +195,7 @@ def _emit_dpm_partition(
         remaining = [d for d in rest if d not in set(deliver)]
         for d in remaining:
             plan.paths.append(
-                PacketPath(unicast(rep, d), [d], parent=parent_idx)
+                PacketPath(xy_route(g, rep, d), [d], parent=parent_idx)
             )
 
 

@@ -101,6 +101,7 @@ def test_batched_plans_bit_identical_all_kinds(kind, algo, cm):
     for (src, dests), pb in zip(reqs, got):
         assert pb == plan(algo, g, src, dests, cost_model=cm)
     assert bp.info().batched_plans == len(reqs)
+    assert bp.info().array_decoded == len(reqs)
     assert bp.info().host_plans == 0
 
 
@@ -361,20 +362,20 @@ def test_registry_change_clears_arenas():
     assert arena_info().misses == 0
 
 
-def test_batch_padding_and_multi_chunk_batches():
+@pytest.mark.parametrize("n", [1, 7, 512, 513])
+def test_batch_padding_and_multi_chunk_batches(n):
+    """One request, a few (padded to a power of two), exactly one
+    ``DISPATCH_CHUNK`` and one past it (a second, padded chunk): every
+    plan of every chunk equals host ``plan()``."""
     g = grid(4)
     bp = BatchPlanner(g, "DPM")
-    one = bp.plan_many(_requests(g, 1, seed=21))
-    assert len(one) == 1
-    n = bpm.DISPATCH_CHUNK + 3  # forces a second (padded) chunk
-    reqs = _requests(g, n, seed=22, kmax=6)
+    reqs = _requests(g, n, seed=22 + n, kmax=6)
     got = bp.plan_many(reqs)
     assert len(got) == n
-    assert bp.info().dispatches >= 3  # 1 + ceil(n / DISPATCH_CHUNK)
-    sample = random.Random(0).sample(range(n), 12)
-    for i in sample:
-        src, dests = reqs[i]
-        assert got[i] == plan("DPM", g, src, dests)
+    assert bp.info().dispatches == -(-n // bpm.DISPATCH_CHUNK)
+    assert bp.info().array_decoded == n
+    for (src, dests), pb in zip(reqs, got):
+        assert pb == plan("DPM", g, src, dests)
 
 
 @pytest.mark.parametrize("enabled", [True, False], ids=["on", "off"])
@@ -521,6 +522,90 @@ def test_source_among_dests_is_already_delivered(k):
         assert pb == plan("DPM", g, src, dests)
 
 
+def _has_shape(g, src, dests, shape):
+    """Whether host Algorithm 1 gives (src, dests) a partition of
+    ``shape``: a lone member, an MU-mode partition of several, a DP
+    partition with members on both label sides of its representative
+    (a sibling worm), a head S -> R that passes destinations it does not
+    deliver (another partition's: the representative is its partition's
+    nearest member, so a shortest head passes none of its own), or the
+    source among the destinations."""
+    from repro.core.partition import dpm_partition
+    from repro.core.routing import xy_route
+
+    if shape == "source_in_dests":
+        return src in dests
+    for p in dpm_partition(g, src, dests).partitions:
+        if not p.dests:
+            continue
+        if shape == "singleton" and len(p.dests) == 1:
+            return True
+        if shape == "mu" and p.mode == "MU" and len(p.dests) > 1:
+            return True
+        lr = g.label(*p.rep)
+        sides = {g.label(*d) > lr for d in p.dests if d != p.rep}
+        if shape == "sibling" and p.mode == "DP" and len(sides) == 2:
+            return True
+        if shape == "head_passes" and set(
+                xy_route(g, src, p.rep)[1:-1]) & set(dests):
+            return True
+    return False
+
+
+# (shape, mesh side, fanouts); the 32x32 case is the bulk cell's fabric
+SHAPES = [("singleton", 8, (1, 5)), ("mu", 8, (2, 8)),
+          ("sibling", 8, (4, 16)), ("source_in_dests", 8, (2, 16)),
+          ("head_passes", 8, (10, 16)), ("head_passes", 32, (10, 16))]
+
+
+@pytest.mark.parametrize("shape,n,fanouts", SHAPES,
+                         ids=[f"{s}-{n}x{n}" for s, n, _ in SHAPES])
+def test_array_decode_bit_identical_by_partition_shape(shape, n, fanouts):
+    """The chunk decode on instances that each hold one partition shape
+    of the host emitter: every plan equals host ``plan()``, all of them
+    decoded by the array path."""
+    g = grid(n)
+    nodes = g.nodes()
+    rng = random.Random(sum(map(ord, shape)) + n)
+    reqs = []
+    for _ in range(20_000):
+        src = rng.choice(nodes)
+        dests = rng.sample([u for u in nodes if u != src],
+                           rng.randint(*fanouts))
+        if shape == "source_in_dests":
+            dests.append(src)
+        if _has_shape(g, src, dests, shape):
+            reqs.append((src, sorted(dests)))
+            if len(reqs) == 24:
+                break
+    assert len(reqs) == 24
+    bp = BatchPlanner(g, "DPM")
+    for (src, dests), pb in zip(reqs, bp.plan_many(reqs)):
+        assert pb == plan("DPM", g, src, dests)
+    info = bp.info()
+    assert info.array_decoded == info.batched_plans == len(reqs)
+
+
+def test_array_decode_counts_every_device_plan(monkeypatch):
+    """On a healthy mesh every plan planned on the device is decoded by
+    the array path, whose chains skip nothing (no pass tables); a host-
+    planned objective decodes nothing there."""
+    calls = []
+    monkeypatch.setattr(BatchPlanner, "_chain_visits",
+                        lambda self, *a: calls.append(a))
+    g = grid(8)
+    bp = BatchPlanner(g, "DPM")
+    assert bp._tables().ph is None
+    reqs = _requests(g, 40, seed=8, kmax=16)
+    bp.plan_many(reqs)
+    info = bp.info()
+    assert info.array_decoded == info.batched_plans == len(reqs)
+    assert not calls
+    host = BatchPlanner(g, "DPM-E")
+    host.plan_many(reqs[:4])
+    assert host.info().array_decoded == 0 and host.info().host_plans == 4
+
+
 # ---------------------------------------------------------------------------
 # Degraded meshes on the device path
 # ---------------------------------------------------------------------------
@@ -575,6 +660,33 @@ def test_degraded_mesh_plans_on_device_bit_identical(n, faults):
         assert pb == plan("DPM", g, src, dests)
     info = planner_for(g, "DPM").info()
     assert info.host_plans == 0 and info.batched_plans == len(reqs)
+    assert info.array_decoded == len(reqs)
+
+
+def test_degraded_fabric_decodes_on_the_array_path(monkeypatch):
+    """A degraded fabric whose label routes pass later chain members
+    (pass tables present) takes the array decode too: its chains skip the
+    members an earlier route passed, and every plan equals host
+    ``plan()``."""
+    g, _ = _degraded(8, 12)
+    bp = BatchPlanner(g, "DPM")
+    assert bp._tables().ph is not None
+    skipped = []
+    real = BatchPlanner._chain_visits
+
+    def spy(self, *a):
+        go = real(self, *a)
+        skipped.append(int((~go).sum()))
+        return go
+
+    monkeypatch.setattr(BatchPlanner, "_chain_visits", spy)
+    reqs = _requests(g, 256, seed=812, kmax=16)
+    for (src, dests), pb in zip(reqs, bp.plan_many(reqs)):
+        assert pb == plan("DPM", g, src, dests)
+    info = bp.info()
+    assert info.array_decoded == info.batched_plans == len(reqs)
+    assert info.host_plans == 0
+    assert sum(skipped) > 0
 
 
 def test_unreachable_destination_raises_through_both_paths():
@@ -650,7 +762,7 @@ def test_degraded_device_candidates_match_definitions(n, faults):
             assert dist[iu, iv] == g.distance(u, v)
             assert w_uni[iu, iv] == len(provider_for(g).unicast(g, u, v)) - 1
     bp = BatchPlanner(g, "DPM")
-    memb = bp._tables().memb_rows
+    memb = bp._tables().memb
     rng = random.Random(n)
     nodes = [u for u in g.nodes() if u not in dead]
     reqs = [(src, sorted(rng.sample([u for u in nodes if u != src], 12)))
