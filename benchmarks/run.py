@@ -3,8 +3,8 @@
 Prints ``name,us_per_call,derived`` CSV rows. ``--quick`` trims sweep sizes.
 A suite that raises prints an ``<suite>/ERROR`` row, the remaining suites
 still run, and the process exits non-zero.
-Roofline numbers come from the dry-run artifacts (benchmarks/dryrun_results,
-summarized by benchmarks/roofline_table.py), not from wall-time here.
+The system's speed on the chip is measured by ``bench/`` (see PERF.md),
+not by wall-time here.
 """
 from __future__ import annotations
 
@@ -21,8 +21,8 @@ def main() -> None:
         "--only",
         default=None,
         help="comma-separated module names "
-        "(fig6,fig7,fig8,partition,tpu,torus,kernels,dist,xsim,fault,trace,"
-        "telemetry,topo3d,planserve)",
+        "(fig6,fig7,fig8,partition,tpu,torus,dist,fault,trace,telemetry,"
+        "topo3d)",
     )
     ap.add_argument(
         "--algos",
@@ -45,15 +45,12 @@ def main() -> None:
         fig6_latency,
         fig7_power,
         fig8_traces,
-        kernels_micro,
         partition_quality,
-        planserve,
         telemetry_calibration,
         topo3d_sweep,
         torus_planner,
         tpu_multicast,
         trace_replay,
-        xsim_sweep,
     )
 
     suites = {
@@ -63,14 +60,11 @@ def main() -> None:
         "partition": partition_quality.run,
         "tpu": tpu_multicast.run,
         "torus": torus_planner.run,
-        "kernels": kernels_micro.run,
         "dist": dist_collectives.run,
-        "xsim": xsim_sweep.run,
         "fault": fault_resilience.run,
         "trace": trace_replay.run,
         "telemetry": telemetry_calibration.run,
         "topo3d": topo3d_sweep.run,
-        "planserve": planserve.run,
     }
     only = set(args.only.split(",")) if args.only else set(suites)
     unknown = only - set(suites)

@@ -45,7 +45,7 @@ import time
 
 ROOT = pathlib.Path(__file__).resolve().parent
 
-FANOUT = (8, 24)  # the serving fanout of BENCH_planserve.json
+FANOUT = (8, 24)  # destinations per multicast in the planner phase
 PLANNER_FABRICS = (
     # (mesh side, bulk instances, host-checked sample or None = all,
     #  PlanServer stream)

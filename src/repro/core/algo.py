@@ -104,7 +104,7 @@ class HopCountCost(CostModel):
     """The paper's Definition 2 exactly: integer hop counts, no NI term.
 
     This is the default model; ``dpm_partition`` under it is bit-identical
-    to the pre-registry behaviour (and to the Pallas ``dpm_cost`` tables).
+    to the pre-registry behaviour.
     """
 
     name = "hops"

@@ -185,14 +185,36 @@ class ArenaCacheInfo(NamedTuple):
 # ---------------------------------------------------------------------------
 # Dense host tables (cached per topology / cost model)
 # ---------------------------------------------------------------------------
+def partition_membership(g, srcs) -> np.ndarray:
+    """(len(srcs), NN) int32 wedge id of every node w.r.t. each source.
+
+    Entry ``[p, v]`` is the basic-partition index of node ``v`` under
+    source ``srcs[p]`` (``core.partition.wedge_patterns`` order over sign
+    patterns of ``Topology.delta``), or -1 at the source itself.
+    """
+    nodes = g.nodes()
+    ndim = len(nodes[0])
+    index = {p: i for i, p in enumerate(wedge_patterns(ndim))}
+    out = np.full((len(srcs), g.num_nodes), -1, np.int32)
+    for pi, src in enumerate(srcs):
+        for v in nodes:
+            dv = g.delta(src, v)
+            sign = tuple((x > 0) - (x < 0) for x in dv)
+            out[pi, g.idx(v)] = index.get(sign, -1)
+    return out
+
+
+def snake_labels(g) -> np.ndarray:
+    """(NN,) int32 boustrophedon label per node, ``Topology.idx`` order."""
+    return np.array([g.label(*c) for c in g.nodes()], np.int32)
+
+
 @functools.lru_cache(maxsize=256)
 def membership_table(topo: MeshGrid) -> np.ndarray:
     """(NN, NN) int32 wedge id of node ``v`` w.r.t. source ``u`` for every
     pair — the all-sources ``partition_membership`` table, built once per
     topology so batch packing is a row gather instead of per-request host
     geometry."""
-    from ..kernels.dpm_cost.ops import partition_membership
-
     return partition_membership(topo, topo.nodes())
 
 
@@ -314,7 +336,7 @@ def batch_support(topo: MeshGrid, algo="DPM", cost_model=None) -> _Support:
             f"{topo.num_nodes} nodes > MAX_ARENA_NODES ({MAX_ARENA_NODES})",
         )
     dist, w_uni, overhead = route_cost_matrices(topo, cm)
-    from ..kernels.dpm_cost.dpm_cost import BIG
+    from ..kernels.dpm_cost.ops import BIG
 
     if int(dist.max(initial=0)) * BIG + topo.num_nodes >= 2**31:
         return _Support(False, "route distances overflow the int32 rep key")
@@ -581,8 +603,6 @@ class BatchPlanner:
     def _tables(self) -> _Tables:
         if self._tables_cached is None:
             import jax.numpy as jnp
-
-            from ..kernels.dpm_cost.ops import snake_labels
 
             with span("repro.planner.tables"):
                 dist, w_uni, overhead = route_cost_matrices(

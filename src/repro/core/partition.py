@@ -32,8 +32,8 @@ def wedge_patterns(ndim: int) -> tuple[tuple[int, ...], ...]:
     the dz=0 ring first (the 2-D order, so a flat destination set partitions
     identically to the 2-D case), then the dz=+1 block of 9 (the 8 ring
     patterns followed by the (0,0,+1) pole), then the dz=-1 block. This is
-    the order the dpm_cost kernels' partition-membership tables are built in
-    (``kernels/dpm_cost``) — keep them in lockstep.
+    the order the device planner's partition-membership tables are built
+    in (``core.batch_planner.partition_membership``).
     """
     if ndim == 2:
         return _RING2

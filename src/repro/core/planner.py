@@ -6,9 +6,9 @@ nodes where a copy is absorbed. DPM paths may spawn *child* packets at the
 representative node (the MU-mode re-injection); the simulator honours the
 dependency, and hop-count accounting sums parent and child paths.
 
-These planners run on the host (plan/trace time); the vectorized cost-table
-computation also exists as a Pallas kernel (kernels/dpm_cost) with a jnp
-reference, validated against this module.
+These planners run on the host (plan/trace time). DPM and DPM-E also plan
+in batches on the device (``core.batch_planner``, whose merge is
+``kernels/dpm_cost.dpm_plan_exact``), bit-identical to this module.
 """
 from __future__ import annotations
 

@@ -27,7 +27,7 @@ lifts that assumption into an explicit layer (DESIGN.md §7):
 ``faulty(topo, ())``, which returns the base unchanged) get the minimal
 provider; degraded topologies get the fault-aware one. ``route_cost_matrices``
 lowers a (topology, cost model) pair to the dense per-pair tensors the
-weighted Pallas planner kernel (kernels/dpm_cost) consumes.
+device planner (``kernels/dpm_cost.dpm_plan_exact``) consumes.
 """
 from __future__ import annotations
 
@@ -268,7 +268,7 @@ class RouteProvider:
     ``unicast`` returns the full hop sequence (inclusive of both endpoints);
     ``label_step`` advances one hop of the dual-path (Lin-McKinley) routing
     function; ``link_weights`` prices every directed link for device-side
-    batched planning (the weighted dpm_cost kernel).
+    batched planning (``route_cost_matrices``).
     """
 
     name = "abstract"
@@ -477,7 +477,7 @@ def provider_for(topo: MeshGrid) -> RouteProvider:
 
 
 # ---------------------------------------------------------------------------
-# Dense lowering for the weighted Pallas planner kernel
+# Dense lowering for the device planner
 # ---------------------------------------------------------------------------
 @functools.lru_cache(maxsize=256)
 def components(topo: MeshGrid) -> np.ndarray:
@@ -526,8 +526,8 @@ def _route_cost_matrices_cached(topo: MeshGrid, cm) -> tuple:
 def route_cost_matrices(
     topo: MeshGrid, cost_model=None
 ) -> tuple[np.ndarray, np.ndarray, float]:
-    """Lower (topology, cost model) to the dense tensors the weighted
-    ``kernels/dpm_cost`` path batches over:
+    """Lower (topology, cost model) to the dense tensors the device
+    planner (``kernels/dpm_cost.dpm_plan_exact``) batches over:
 
     * ``dist[u, v]``   int32 provider-route hop count (detours included) —
       the Definition 1 representative-selection metric;

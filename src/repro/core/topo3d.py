@@ -14,7 +14,7 @@ simulators, and the telemetry link indexing apply unchanged:
   (x, y) of the adjacent layer (a z-link) — a Hamiltonian path, so
   label-monotone dual-path routing stays valid exactly as on the 2-D
   mesh. ``delta`` is the signed per-dimension shortest displacement with
-  the kernels' half-way tie-break on the torus. TSV z-links carry a
+  the 2-D torus's half-way tie-break. TSV z-links carry a
   ``z_weight`` price class (>= 1.0) that the weighted cost path prices.
 * ``ChipletPackage`` — cx x cy chiplets of cw x ch routers each, in one
   global coordinate frame. Within-chiplet links form the full 2-D mesh;
@@ -173,7 +173,7 @@ class Mesh3D:
 @dataclass(frozen=True)
 class Torus3D(Mesh3D):
     """nx x ny x nz wraparound 3-D torus (shortest-way-around deltas with
-    the kernels' half-way tie-break, per dimension independently)."""
+    the 2-D torus's half-way tie-break, per dimension independently)."""
 
     kind = "torus3d"
     wrap = True

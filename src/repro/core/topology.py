@@ -13,8 +13,8 @@ implementations:
   only needs the snake successor to be a neighbor.
 * **delta / distance** — the signed shortest per-dimension displacement. On
   a torus each dimension independently takes the shorter way around the
-  ring; an exact half-way tie breaks toward the negative direction, matching
-  the kernels' ``((d + size//2) % size) - size//2`` formula bit for bit.
+  ring; an exact half-way tie breaks toward the negative direction:
+  ``((d + size//2) % size) - size//2``.
 * **neighbors / normalize** — wrap links and coordinate canonicalization.
 
 The 8-partition geometry of Definitions 1-3 generalizes through ``delta``:
@@ -66,8 +66,8 @@ def ring_delta(d: int, size: int) -> int:
     """Signed shortest displacement on a ring of ``size`` nodes.
 
     Result lies in [-size//2, (size-1)//2]; an exact half-way tie (even
-    ``size``) goes negative — the same convention as the Pallas kernel's
-    wrapped-distance formula, so host and device partitions always agree.
+    ``size``) goes negative. The device planner's partition tables are
+    built from it, so host and device partitions always agree.
     """
     if size <= 1:
         return 0

@@ -1,10 +1,11 @@
-"""Jit'd DPM planner fast path: kernel cost table + vectorized greedy merge.
+"""The device merge: Algorithm 1 batched, on the device, for the planner.
 
-``dpm_plan(dest_mask, src_xy)`` returns, fully on device and batched over
-packets, the final partition selection of Algorithm 1 under the MU cost
-model: a (P, 24) bool matrix of chosen candidates. Used by the TPU multicast
-scheduler for batched plan evaluation, and validated against the host
-planner (repro.core) in tests.
+``dpm_plan_exact`` prices every DPM candidate of a batch of multicast
+instances under the full Definition 2 objective (C_t and C_p, MU/DP mode)
+and runs the greedy merge, returning everything ``core.batch_planner``
+needs to decode each ``MulticastPlan`` bit-identically to host ``plan()``.
+The geometry enters as host-built tables (``core.batch_planner``), so one
+program serves every registered topology, healthy or degraded.
 """
 from __future__ import annotations
 
@@ -14,10 +15,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...core.partition import candidate_ids_for, wedge_patterns
-from .dpm_cost import BIG, CANDS, dpm_cost_table, dpm_cost_table_weighted
+from ...core.partition import candidate_ids_for
 
-_SINGLES = jnp.arange(8)
+# Scale of the Definition 1 representative key ``dist * BIG + label``:
+# ``core.batch_planner.batch_support`` gates on ``max dist * BIG + NN``
+# staying inside int32, so it must read this same constant.
+BIG = 1 << 20
 
 
 @functools.lru_cache(maxsize=None)
@@ -32,66 +35,20 @@ def _cand_bits(np_: int) -> np.ndarray:
     )
 
 
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
-
-
-@functools.partial(
-    jax.jit, static_argnames=("n", "m", "wrap", "include_source_leg", "interpret")
-)
-def dpm_plan(
-    dest_mask: jax.Array,  # (P, NN)
-    src_xy: jax.Array,  # (P, 2)
-    *,
-    n: int,
-    m: int | None = None,
-    wrap: bool = False,
-    include_source_leg: bool = True,
-    interpret: bool | None = None,
-):
-    """Algorithm 1 (greedy partition merging), batched. Returns
-    (chosen (P,24) bool, costs (P,24) int32, reps (P,24) int32).
-    ``wrap=True`` plans on torus geometry (toroidal distances/partitions)."""
-    if interpret is None:
-        interpret = _on_cpu()
-    costs, reps = dpm_cost_table(
-        dest_mask,
-        src_xy,
-        n=n,
-        m=m,
-        wrap=wrap,
-        include_source_leg=include_source_leg,
-        interpret=interpret,
-    )
-    # greedy merge (Definition 3 savings + tie-breaks) shared with the
-    # weighted path — int32 costs keep the original integer arithmetic
-    return _greedy_merge(costs, reps), costs, reps
-
-
-def total_plan_cost(chosen, costs):
-    return jnp.sum(jnp.where(chosen, costs, 0), axis=1)
-
-
 # order sentinel: "never picked by the merge loop" (leftover singles sort
 # after every real pick round; see _greedy_merge_ordered)
 NO_ORDER = jnp.int32(2**30)
 
 
-def _greedy_merge(costs, reps, np_: int = 8):
-    """Algorithm 1's greedy merge over an already-computed candidate table.
+def _greedy_merge_ordered(costs, reps, np_: int):
+    """Algorithm 1's greedy merge over a priced candidate table, with the
+    *pick order*: ``(chosen, order)``.
 
-    Shared by the hop-count, weighted, and generic-topology paths; ``costs``
-    may be int32 (hop counting) or float32 (weighted objectives) — savings
-    stay in the input dtype and the host tie-break is reproduced exactly in
-    either. ``np_`` is the basic-partition count (8 wedges in 2-D, 26 in
-    3-D); the candidate axis is ``3 * np_`` (singles + consecutive pairs +
-    triples, ``core.partition.candidate_ids_for`` order).
-    """
-    return _greedy_merge_ordered(costs, reps, np_)[0]
-
-
-def _greedy_merge_ordered(costs, reps, np_: int = 8):
-    """Greedy merge that also reports *pick order*: ``(chosen, order)``.
+    ``costs`` are float32 and savings stay in that dtype; the host
+    tie-break is reproduced exactly. ``np_`` is the basic-partition count
+    (8 wedges in 2-D, 26 in 3-D); the candidate axis is ``3 * np_``
+    (singles + consecutive pairs + triples, ``core.partition.
+    candidate_ids_for`` order).
 
     ``order[p, ci]`` is the merge round (0-based) at which candidate ``ci``
     won, or ``NO_ORDER`` for unpicked candidates and leftover singles. The
@@ -165,134 +122,13 @@ def _greedy_merge_ordered(costs, reps, np_: int = 8):
     return chosen, order
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("n", "m", "wrap", "overhead", "include_source_leg",
-                     "interpret"),
-)
-def dpm_plan_weighted(
-    dest_mask: jax.Array,  # (P, NN)
-    src_xy: jax.Array,  # (P, 2)
-    dist: jax.Array,  # (NN, NN) provider-route hop counts
-    weight: jax.Array,  # (NN, NN) provider-route prices
-    *,
-    n: int,
-    m: int | None = None,
-    wrap: bool = False,
-    overhead: float = 0.0,
-    include_source_leg: bool = True,
-    interpret: bool | None = None,
-):
-    """Algorithm 1 batched under an arbitrary route-cost tensor.
-
-    The device twin of ``dpm_partition(..., cost_model=...)`` restricted to
-    MU-mode candidate pricing: ``(dist, weight, overhead)`` come from
-    ``repro.core.routefn.route_cost_matrices``, so energy / contention /
-    fault-penalty DPM (including detoured routes on a ``FaultyTopology``)
-    batch on device. Returns (chosen (P,24) bool, costs (P,24) f32,
-    reps (P,24) i32).
-    """
-    if interpret is None:
-        interpret = _on_cpu()
-    costs, reps = dpm_cost_table_weighted(
-        dest_mask, src_xy, dist, weight,
-        n=n, m=m, wrap=wrap, overhead=overhead,
-        include_source_leg=include_source_leg, interpret=interpret,
-    )
-    return _greedy_merge(costs, reps), costs, reps
-
-
-# ---------------------------------------------------------------------------
-# Generic-topology path: 3-D meshes/tori (26 wedges) and chiplet packages
-# route their geometry through host-built lookup tables instead of the
-# closed-form 2-D coordinate math baked into the Pallas kernels above.
-# ---------------------------------------------------------------------------
-def partition_membership(g, srcs) -> np.ndarray:
-    """(len(srcs), NN) int32 wedge id of every node w.r.t. each source.
-
-    Entry ``[p, v]`` is the basic-partition index of node ``v`` under
-    packet ``p``'s source (``core.partition.wedge_patterns`` order over
-    sign patterns of ``Topology.delta``), or -1 at the source itself —
-    the membership table ``dpm_plan_topo`` selects candidates from.
-    """
-    nodes = g.nodes()
-    ndim = len(nodes[0])
-    index = {p: i for i, p in enumerate(wedge_patterns(ndim))}
-    out = np.full((len(srcs), g.num_nodes), -1, np.int32)
-    for pi, src in enumerate(srcs):
-        for v in nodes:
-            dv = g.delta(src, v)
-            sign = tuple((x > 0) - (x < 0) for x in dv)
-            out[pi, g.idx(v)] = index.get(sign, -1)
-    return out
-
-
-def snake_labels(g) -> np.ndarray:
-    """(NN,) int32 boustrophedon label per node, ``Topology.idx`` order."""
-    return np.array([g.label(*c) for c in g.nodes()], np.int32)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("np_", "overhead", "include_source_leg")
-)
-def dpm_plan_topo(
-    part_of: jax.Array,  # (P, NN) int32 membership (partition_membership)
-    src_idx: jax.Array,  # (P,) int32 Topology.idx of each source
-    labels: jax.Array,  # (NN,) int32 snake labels (snake_labels)
-    dist: jax.Array,  # (NN, NN) provider-route hop counts
-    weight: jax.Array,  # (NN, NN) provider-route prices
-    *,
-    np_: int,
-    overhead: float = 0.0,
-    include_source_leg: bool = True,
-):
-    """Algorithm 1 batched on *any* registered topology.
-
-    The geometry enters as data: wedge membership (masking non-destinations
-    with -1), snake labels, and the ``(dist, weight, overhead)`` route-cost
-    tensors of ``repro.core.routefn.route_cost_matrices`` — so 3-D meshes,
-    tori, and chiplet packages (including degraded/weighted fabrics) batch
-    on device with no kernel-side coordinate math. ``np_`` is
-    ``len(core.partition.wedge_patterns(ndim))``: 8 in 2-D, 26 in 3-D.
-    Returns (chosen (P, 3*np_) bool, costs (P, 3*np_) f32,
-    reps (P, 3*np_) i32), candidate axis in ``candidate_ids_for`` order.
-    """
-    cands = candidate_ids_for(np_)
-    dist = dist.astype(jnp.int32)
-    weight = weight.astype(jnp.float32)
-    dsrc = jnp.take(dist, src_idx, axis=0)  # (P, NN)
-    w_src = jnp.take(weight, src_idx, axis=0)
-    costs, reps = [], []
-    for ids in cands:
-        sel = part_of == ids[0]
-        for i in ids[1:]:
-            sel = sel | (part_of == i)
-        any_sel = sel.any(1)
-        # Definition 1 representative: min (dist-to-src, label)
-        key = jnp.where(sel, dsrc * BIG + labels[None], jnp.int32(2**30))
-        rep = jnp.argmin(key, 1).astype(jnp.int32)
-        w_rep = jnp.take(weight, rep, axis=0)  # (P, NN) prices from rep
-        cnt = jnp.sum(sel.astype(jnp.float32), 1)
-        ct = jnp.sum(jnp.where(sel, w_rep, 0.0), 1)
-        ct = ct + jnp.maximum(cnt - 1.0, 0.0) * float(overhead)
-        if include_source_leg:
-            ct = ct + jnp.take_along_axis(w_src, rep[:, None], 1)[:, 0]
-        costs.append(jnp.where(any_sel, ct, 0.0))
-        reps.append(jnp.where(any_sel, rep, -1))
-    costs = jnp.stack(costs, 1)
-    reps = jnp.stack(reps, 1)
-    return _greedy_merge(costs, reps, np_), costs, reps
-
-
 def _pick(onehot, x):
     """``x`` at the one slot ``onehot`` marks along its last axis, as a
     where-sum: one term plus zeros, so exact in any dtype."""
     return jnp.sum(jnp.where(onehot, x, jnp.zeros((), x.dtype)), axis=-1)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("np_", "overhead", "include_source_leg")
-)
+@functools.partial(jax.jit, static_argnames=("np_", "overhead"))
 def dpm_plan_exact(
     dests: jax.Array,  # (B, K) int32 destination node indices, -1 pads
     src_idx: jax.Array,  # (B,) int32 Topology.idx of each source
@@ -307,13 +143,12 @@ def dpm_plan_exact(
     *,
     np_: int,
     overhead: float = 0.0,
-    include_source_leg: bool = True,
 ):
     """Algorithm 1 batched with the *full* Definition 2 objective.
 
-    Unlike ``dpm_plan_topo`` (which prices candidates by C_t only), this
-    evaluates both C_t and C_p per candidate and records the MU/DP mode
-    choice and the greedy pick order, everything the host decode needs to
+    Evaluates both C_t and C_p per candidate, plus the source leg S -> R,
+    and records the MU/DP mode choice and the greedy pick order,
+    everything the host decode needs to
     rebuild each ``MulticastPlan`` bit-identically (``core.batch_planner``;
     exactness conditions in ``batch_support`` there). Each instance is its
     source and up to K destination slots. Returns ``(chosen, order, reps,
@@ -445,8 +280,7 @@ def dpm_plan_exact(
     # ties prefer MU (the paper: D_H/D_L computation is then skipped)
     mode_mu = cost_mu <= cost_dp
     cost = jnp.minimum(cost_mu, cost_dp)
-    if include_source_leg:
-        cost = cost + _pick(at_r, w_src[None])
+    cost = cost + _pick(at_r, w_src[None])
     costs = jnp.where(any_sel, cost, 0.0).T
     reps = jnp.where(any_sel, rep, -1).T
     modes = (mode_mu | ~any_sel).T

@@ -1,8 +1,7 @@
-"""Fused wormhole-cycle kernel: the whole xsim step as one Pallas launch.
+"""Fused wormhole-cycle kernel: the whole xsim cycle as one Pallas launch.
 
-Three-file pattern (as ``kernels.noc_step``): ``ref.py`` is the bit-exact
-jnp cycle over packed router-centric planes (the default engine on every
-platform),
+Three files: ``ref.py`` is the bit-exact jnp cycle over packed
+router-centric planes (the default engine on every platform),
 ``noc_cycle.py`` the Pallas chunk kernel running the same ``cycle_core``
 with state resident across an inner ``fori_loop``, ``ops.py`` the backend
 dispatch (``ref`` / ``pallas`` / ``pallas_interpret``).

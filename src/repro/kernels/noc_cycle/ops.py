@@ -16,22 +16,34 @@ the simulation outputs (``dtime``, counters, released-children mask):
   gathers of ``cycle_core`` (the first is ``take_along_axis`` in
   ``ref.py``).
 
-Backend names resolve through ``kernels.noc_step.ops.resolve_backend``
-(``None``/``"auto"`` picks ``ref`` everywhere), so the whole xsim stack
-shares one switch.
+Backend names resolve through ``resolve_backend`` (``None``/``"auto"``
+picks ``ref`` everywhere), so the whole xsim stack shares one switch.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from ..noc_step.ops import resolve_backend  # noqa: F401  (re-export)
 from .noc_cycle import make_chunk_runner
 from .ref import CTR, TABLE_FIELDS, CycleState, cycle_core, init_planes
 
 __all__ = [
     "CTR", "CycleState", "init_planes", "resolve_backend", "run_cycles",
 ]
+
+
+def resolve_backend(backend: str | None) -> str:
+    """``None``/"auto" -> "ref" on every platform.
+
+    The fused ``noc_cycle`` Pallas kernel does not lower through Mosaic yet
+    (its gathers are refused), so the compiled ``lax.scan`` of the same
+    ``cycle_core`` is the engine on TPU as on CPU; ``"pallas"`` stays
+    callable explicitly."""
+    if backend in (None, "auto"):
+        return "ref"
+    if backend not in ("ref", "pallas", "pallas_interpret"):
+        raise ValueError(f"unknown xsim backend: {backend!r}")
+    return backend
 
 
 def run_cycles(tr: dict, geom: dict, *, T: int, F: int, V: int, BD: int,

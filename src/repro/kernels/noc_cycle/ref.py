@@ -49,7 +49,9 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..noc_step.noc_step import NOC_INF
+# Large sentinel: above every real key, far from int32 overflow when compared.
+# A plain Python int so kernels can close over it without a captured constant.
+NOC_INF = 2**30
 
 # counter indices (named after the SimStats fields they feed; slots_hwm is
 # xsim-only: the in-flight-worm high-water mark)

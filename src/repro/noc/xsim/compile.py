@@ -1,4 +1,4 @@
-"""Lower host-side MulticastPlans into the dense tensors xsim steps over.
+"""Lower host-side MulticastPlans into the dense tensors of the xsim cycle.
 
 The compiler mirrors ``WormholeSim.add_plan`` exactly: one row per wormhole
 packet (degenerate single-node paths are skipped, DPM child packets keep

@@ -31,6 +31,7 @@ from ..simulator import SimStats
 from ..traffic import Workload
 from ...core.algo import available_algorithms, get_algorithm
 from ...core.topology import make_topology
+from ...kernels.noc_cycle import CTR, run_cycles
 from ...obs import span
 from .compile import (
     CompiledTraffic,
@@ -38,7 +39,6 @@ from .compile import (
     geometry_tables,
     stack_traffic,
 )
-from .step import CTR, run_cycles
 
 
 def _run_one(tr: dict, T: int, F: int, V: int, BD: int, L: int, NN: int,

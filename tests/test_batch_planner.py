@@ -736,37 +736,75 @@ def test_degraded_chain_matrices_match_label_walks(n, faults):
     assert label_chain_passes(grid(n)) is None
 
 
-@pytest.mark.parametrize("n,faults", [(8, 12), (8, "router")])
-def test_degraded_device_candidates_match_definitions(n, faults):
+# ---------------------------------------------------------------------------
+# The device merge against Definitions 1-3, fabric by fabric
+# ---------------------------------------------------------------------------
+# every registered kind at several sizes and aspect ratios, healthy and
+# degraded: (topology, failed routers kept out of the requests)
+MERGE_FABRICS = {
+    "mesh4x4": lambda: (grid(4), []),
+    "mesh6x6": lambda: (grid(6), []),
+    "mesh8x8": lambda: (grid(8), []),
+    "mesh16x16": lambda: (grid(16), []),
+    "mesh8x4": lambda: (grid(8, 4), []),
+    "torus5x5": lambda: (torus(5, 5), []),
+    "torus8x8": lambda: (torus(8, 8), []),
+    "torus6x4": lambda: (torus(6, 4), []),
+    "mesh3d": lambda: (mesh3d(3, 3, 3, z_weight=2.0), []),
+    "torus3d": lambda: (torus3d(3, 3, 2), []),
+    "chiplet": lambda: (chiplet(4), []),
+    "degraded8x8-12": lambda: _degraded(8, 12),
+    "degraded8x8-router": lambda: _degraded(8, "router"),
+}
+
+
+def _merge_requests(g, dead, count, seed):
+    """``count`` instances of fanout 1 to MIN_SLOTS over the live nodes."""
+    rng = random.Random(seed)
+    nodes = [u for u in g.nodes() if u not in dead]
+    reqs = []
+    for _ in range(count):
+        src = rng.choice(nodes)
+        k = rng.randint(1, min(bpm.MIN_SLOTS, len(nodes) - 1))
+        reqs.append((src, sorted(rng.sample(
+            [u for u in nodes if u != src], k))))
+    return reqs
+
+
+@pytest.mark.parametrize("cm", ["hops", "weighted"])
+@pytest.mark.parametrize("fabric", sorted(MERGE_FABRICS))
+def test_device_candidates_match_definitions(fabric, cm):
     """The device tables and the merge's per-candidate outputs follow the
-    host's Definitions 1-2 on a degraded mesh: wedge membership,
-    provider-route distances and prices, and for every candidate its
-    representative, MU/DP mode and cost."""
+    host's Definitions 1-2 on every fabric kind, healthy and degraded,
+    under hop and weighted prices: provider-route distances and prices
+    (all pairs, on fabrics of up to 64 nodes), wedge membership, and for
+    every candidate its representative, MU/DP mode and cost."""
     import numpy as np
 
-    from repro.core import candidate_cost, route_cost_matrices
+    from repro.core import candidate_cost, get_cost_model, route_cost_matrices
     from repro.core.partition import basic_partitions
     from repro.core.routefn import components, provider_for
 
-    g, dead = _degraded(n, faults)
-    dist, w_uni, _ = route_cost_matrices(g)
-    comp = components(g)
-    for u in g.nodes():
-        for v in g.nodes():
-            iu, iv = g.idx(u), g.idx(v)
-            if u == v:
-                continue
-            if comp[iu] != comp[iv]:
-                assert dist[iu, iv] == -1 and np.isinf(w_uni[iu, iv])
-                continue
-            assert dist[iu, iv] == g.distance(u, v)
-            assert w_uni[iu, iv] == len(provider_for(g).unicast(g, u, v)) - 1
-    bp = BatchPlanner(g, "DPM")
+    g, dead = MERGE_FABRICS[fabric]()
+    model = get_cost_model(cm)
+    if g.num_nodes <= 64:
+        dist, w_uni, _ = route_cost_matrices(g, model)
+        comp = components(g)
+        for u in g.nodes():
+            for v in g.nodes():
+                iu, iv = g.idx(u), g.idx(v)
+                if u == v:
+                    continue
+                if comp[iu] != comp[iv]:
+                    assert dist[iu, iv] == -1 and np.isinf(w_uni[iu, iv])
+                    continue
+                route = provider_for(g).unicast(g, u, v)
+                assert dist[iu, iv] == g.distance(u, v) == len(route) - 1
+                assert w_uni[iu, iv] == model.route_cost(g, route)
+    bp = BatchPlanner(g, "DPM", cm)
+    assert bp.support.ok, bp.support.reason
     memb = bp._tables().memb
-    rng = random.Random(n)
-    nodes = [u for u in g.nodes() if u not in dead]
-    reqs = [(src, sorted(rng.sample([u for u in nodes if u != src], 12)))
-            for src in rng.sample(nodes, 16)]
+    reqs = _merge_requests(g, dead, 16, seed=sum(map(ord, fabric)))
     _, _, reps, modes, costs = _merge(bp, reqs, bpm.MIN_SLOTS, 16)
     for b, (src, dests) in enumerate(reqs):
         parts = basic_partitions(src, dests, g)
@@ -777,7 +815,89 @@ def test_degraded_device_candidates_match_definitions(n, faults):
             if not union:
                 assert reps[b, ci] == -1
                 continue
-            cc = candidate_cost(g, src, ids, union)
+            cc = candidate_cost(g, src, ids, union, model)
             assert reps[b, ci] == g.idx(cc.rep)
             assert bool(modes[b, ci]) == (cc.mode == "MU")
             assert costs[b, ci] == cc.cost(True)
+
+
+def _host_merge(costs, reps, cands, np_):
+    """Algorithm 1's merge loop as ``dpm_partition`` runs it, over one
+    priced candidate row: the candidates it merges, in pick order (max
+    saving, then fewer partitions, then smaller index), and the non-empty
+    singles left over."""
+    savings = {
+        ci: max(0.0, sum(costs[i] for i in ids) - costs[ci])
+        for ci, ids in enumerate(cands) if len(ids) > 1 and reps[ci] >= 0
+    }
+    picks: list[int] = []
+    covered: set[int] = set()
+    while True:
+        best, best_a = None, 0
+        for ci, a in savings.items():
+            if a > 0 and (best is None or a > best_a or (
+                    a == best_a and (len(cands[ci]), cands[ci])
+                    < (len(cands[best]), cands[best]))):
+                best, best_a = ci, a
+        if best is None:
+            break
+        picks.append(best)
+        covered |= set(cands[best])
+        for ci in savings:
+            if covered & set(cands[ci]):
+                savings[ci] = 0
+    return picks, [i for i in range(np_) if i not in covered and reps[i] >= 0]
+
+
+# the energy model prices in floats, where near-tied savings are what a
+# scalar priority encoding would mis-rank; its instances are those of
+# the float tie-break check of the 8x8 merge
+COVER_CASES = sorted(MERGE_FABRICS) + ["energy-mesh8x8"]
+
+
+@pytest.mark.parametrize("case", COVER_CASES)
+def test_device_merge_chooses_a_disjoint_cover(case):
+    """The merge chooses candidates that are disjoint, non-empty and cover
+    every non-empty basic partition, in the order the host's loop picks
+    them over the same prices: merges by round, then the leftover singles.
+    Under the exact price models that is ``dpm_partition``'s own trace."""
+    import numpy as np
+
+    from repro.core.partition import basic_partitions, dpm_partition
+    from repro.kernels.dpm_cost.ops import NO_ORDER
+
+    if case == "energy-mesh8x8":
+        g, dead, models = grid(8), [], ["energy"]
+        rng = random.Random(17)
+        reqs = []
+        for _ in range(40):
+            picks = rng.sample(g.nodes(), rng.randint(1, 16) + 1)
+            reqs.append((picks[0], sorted(picks[1:])))
+    else:
+        (g, dead), models = MERGE_FABRICS[case](), ["hops", "weighted"]
+        reqs = _merge_requests(g, dead, 16, seed=sum(map(ord, case)) + 1)
+    for cm in models:
+        bp = BatchPlanner(g, "DPM", cm)
+        cands, np_ = bp._cands, bp.np_
+        chosen, order, reps, _, costs = _merge(bp, reqs, bpm.MIN_SLOTS,
+                                               len(reqs))
+        for b, (src, dests) in enumerate(reqs):
+            parts = basic_partitions(src, dests, g)
+            picked = np.flatnonzero(chosen[b])
+            assert (reps[b, picked] >= 0).all()
+            covered = [i for ci in picked for i in cands[ci]]
+            assert len(covered) == len(set(covered)), (cm, b)
+            assert set(covered) >= {i for i in range(np_) if parts[i]}
+            rounds = sorted((int(order[b, ci]), ci) for ci in picked
+                            if order[b, ci] != NO_ORDER)
+            assert [r for r, _ in rounds] == list(range(len(rounds)))
+            merges = [ci for _, ci in rounds]
+            leftover = sorted(set(picked) - set(merges))
+            assert (merges, leftover) == _host_merge(
+                costs[b], reps[b], cands, np_), (cm, b)
+            if cm != "energy":
+                host = dpm_partition(g, src, dests, cost_model=cm)
+                assert [cands[ci] for ci in merges] == [
+                    ids for ids, _ in host.savings_trace]
+                assert [cands[ci] for ci in merges + leftover] == [
+                    p.ids for p in host.partitions]

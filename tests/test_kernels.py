@@ -3,8 +3,6 @@
 Per the deliverable: for each kernel, sweep shapes/dtypes and
 assert_allclose against the ref.py oracle.
 """
-import random
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,16 +10,8 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core import candidate_cost, grid
-from repro.core.partition import ALL_CANDIDATE_IDS, basic_partitions
-from repro.kernels.dpm_cost.dpm_cost import CANDS, dpm_cost_table
-from repro.kernels.dpm_cost.ops import dpm_plan, total_plan_cost
-from repro.kernels.dpm_cost.ref import dpm_cost_table_ref
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.noc_step.noc_step import NOC_INF, segmented_min
-from repro.kernels.noc_step.ops import arbitrate
-from repro.kernels.noc_step.ref import segmented_min_ref
 from repro.kernels.ssd.ops import ssd_scan_pallas
 from repro.kernels.ssd.ref import ssd_reference
 
@@ -114,276 +104,6 @@ def test_ssd_kernel_sweep(shape, dtype):
     np.testing.assert_allclose(
         np.asarray(h, np.float32), np.asarray(hr, np.float32), atol=atol
     )
-
-
-# ---------------------------------------------------------------------------
-# noc_step (xsim arbitration segmented-min)
-# ---------------------------------------------------------------------------
-SEGMIN_SHAPES = [
-    # (num candidates, num segments)
-    (64, 7),
-    (1000, 256),
-    (4096, 64),
-    (37, 300),  # more segments than candidates
-    (512, 320),  # the link+ejection fused id space of an 8x8 mesh
-]
-
-
-@pytest.mark.parametrize("shape", SEGMIN_SHAPES)
-def test_noc_step_segmented_min_pallas_vs_ref(shape):
-    N, L = shape
-    rng = np.random.default_rng(N * L)
-    keys = rng.integers(0, 2**22, N).astype(np.int32)
-    keys[rng.random(N) < 0.3] = NOC_INF  # masked (no-candidate) entries
-    segs = rng.integers(0, L, N).astype(np.int32)
-    out_k = segmented_min(jnp.asarray(keys), jnp.asarray(segs), L,
-                          interpret=True)
-    out_r = segmented_min_ref(jnp.asarray(keys), jnp.asarray(segs), L)
-    np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
-    # empty segments must hold exactly NOC_INF on both paths
-    empty = np.setdiff1d(np.arange(L), segs[keys < NOC_INF])
-    assert (np.asarray(out_r)[empty] == NOC_INF).all()
-
-
-@pytest.mark.parametrize("backend", ["ref", "pallas_interpret"])
-def test_noc_step_arbitrate_one_winner_per_resource(backend):
-    rng = np.random.default_rng(9)
-    N, L = 777, 61
-    keys = jnp.asarray(rng.permutation(N).astype(np.int32))  # unique
-    segs = jnp.asarray(rng.integers(0, L, N).astype(np.int32))
-    adm = jnp.asarray(rng.random(N) < 0.4)
-    win = np.asarray(arbitrate(adm, keys, segs, L, backend=backend))
-    assert (win & ~np.asarray(adm)).sum() == 0  # winners are admissible
-    for seg in range(L):
-        mask = (np.asarray(segs) == seg) & np.asarray(adm)
-        if mask.any():
-            # exactly the min-key admissible candidate wins
-            expect = np.flatnonzero(mask)[np.asarray(keys)[mask].argmin()]
-            assert win[np.asarray(segs) == seg].sum() == 1
-            assert win[expect]
-        else:
-            assert win[np.asarray(segs) == seg].sum() == 0
-
-
-# ---------------------------------------------------------------------------
-# dpm_cost
-# ---------------------------------------------------------------------------
-def _instances(n, m, P, seed):
-    g = grid(n, m)
-    rng = random.Random(seed)
-    nodes = [(x, y) for x in range(n) for y in range(m)]
-    masks, sxy, insts = [], [], []
-    for _ in range(P):
-        k = rng.randint(1, min(16, len(nodes) - 1))
-        picks = rng.sample(nodes, k + 1)
-        src, dests = picks[0], picks[1:]
-        row = np.zeros(n * m, np.int32)
-        for (x, y) in dests:
-            row[y * n + x] = 1
-        masks.append(row)
-        sxy.append(src)
-        insts.append((src, dests))
-    return jnp.array(np.stack(masks)), jnp.array(np.array(sxy, np.int32)), insts
-
-
-@pytest.mark.parametrize("mesh", [(4, 4), (8, 8), (16, 16), (8, 4)])
-@pytest.mark.parametrize("leg", [True, False])
-def test_dpm_cost_kernel_vs_ref(mesh, leg):
-    n, m = mesh
-    masks, sxy, _ = _instances(n, m, 33, seed=n * m + leg)
-    ck, rk = dpm_cost_table(
-        masks, sxy, n=n, m=m, include_source_leg=leg, interpret=True, tile=16
-    )
-    cr, rr = dpm_cost_table_ref(masks, sxy, n=n, m=m, include_source_leg=leg)
-    np.testing.assert_array_equal(np.asarray(ck), np.asarray(cr))
-    np.testing.assert_array_equal(np.asarray(rk), np.asarray(rr))
-
-
-def test_dpm_cost_vs_host_planner():
-    """Kernel MU-costs equal the host planner's Definition 1/2 values."""
-    n = 8
-    g = grid(n)
-    masks, sxy, insts = _instances(n, n, 25, seed=3)
-    ck, rk = dpm_cost_table(masks, sxy, n=n, interpret=True, tile=8)
-    for p, (src, dests) in enumerate(insts):
-        parts = basic_partitions(src, dests)
-        for ci, ids in enumerate(ALL_CANDIDATE_IDS):
-            assert CANDS[ci] == ids
-            union = [d for i in ids for d in parts[i]]
-            cc = candidate_cost(g, src, ids, union)
-            host = (cc.cost_mu + cc.source_leg) if union else 0
-            assert host == int(ck[p, ci]), (p, ids)
-            if union:
-                rep = cc.rep
-                assert int(rk[p, ci]) == rep[1] * n + rep[0]
-
-
-def test_dpm_plan_greedy_invariants():
-    """On-device greedy merge: exact disjoint cover of non-empty partitions,
-    and merged selections never increase cost vs unmerged singles."""
-    n = 8
-    masks, sxy, insts = _instances(n, n, 64, seed=11)
-    chosen, costs, reps = dpm_plan(masks, sxy, n=n, interpret=True)
-    bits = np.array([sum(1 << i for i in ids) for ids in CANDS])
-    singles_cost = np.asarray(costs[:, :8])
-    for p, (src, dests) in enumerate(insts):
-        parts = basic_partitions(src, dests)
-        nonempty = sum(1 << i for i in range(8) if parts[i])
-        sel = np.where(np.asarray(chosen[p]))[0]
-        cover = 0
-        for ci in sel:
-            assert cover & bits[ci] & nonempty == 0
-            cover |= bits[ci]
-        assert cover & nonempty == nonempty
-        tot = int(np.asarray(total_plan_cost(chosen, costs))[p])
-        assert tot <= singles_cost[p].sum()  # merging never hurts
-
-
-# ---------------------------------------------------------------------------
-# dpm_cost — weighted route tensors (route-provider layer, DESIGN.md §7)
-# ---------------------------------------------------------------------------
-def test_dpm_cost_weighted_hop_tensors_match_int_kernel():
-    """With hop-count route matrices the weighted kernel reproduces the
-    analytic kernel bit for bit, on mesh and torus geometry."""
-    from repro.core import route_cost_matrices, torus
-    from repro.kernels.dpm_cost.dpm_cost import dpm_cost_table_weighted
-    from repro.kernels.dpm_cost.ref import dpm_cost_table_weighted_ref
-
-    for topo, wrap in ((grid(8), False), (torus(8), True)):
-        masks, sxy, _ = _instances(8, 8, 17, seed=5 + wrap)
-        dist, w, oh = route_cost_matrices(topo)
-        ck, rk = dpm_cost_table(masks, sxy, n=8, wrap=wrap, interpret=True)
-        cw, rw = dpm_cost_table_weighted(
-            masks, sxy, jnp.array(dist), jnp.array(w),
-            n=8, wrap=wrap, overhead=oh, interpret=True, tile=8,
-        )
-        cr, rr = dpm_cost_table_weighted_ref(
-            masks, sxy, jnp.array(dist), jnp.array(w), n=8, wrap=wrap,
-            overhead=oh,
-        )
-        np.testing.assert_array_equal(np.asarray(ck), np.asarray(cw, np.int32))
-        np.testing.assert_array_equal(np.asarray(rk), np.asarray(rw))
-        np.testing.assert_array_equal(np.asarray(cw), np.asarray(cr))
-        np.testing.assert_array_equal(np.asarray(rw), np.asarray(rr))
-
-
-def test_dpm_cost_weighted_vs_host_on_degraded_mesh():
-    """Fault-priced batching: with (dist, weight) lowered from a degraded
-    8x8 mesh the kernel's candidate costs equal the host cost model
-    exactly (detoured integer hop counts), and reps follow the degraded
-    Definition 1 distances."""
-    from repro.core import faulty, get_cost_model, route_cost_matrices
-    from repro.kernels.dpm_cost.dpm_cost import dpm_cost_table_weighted
-
-    n = 8
-    fg = faulty(
-        grid(n), [((3, 3), (4, 3)), ((3, 4), (3, 5)), ((0, 0), (1, 0))]
-    )
-    masks, sxy, insts = _instances(n, n, 21, seed=7)
-    dist, w, oh = route_cost_matrices(fg)
-    cw, rw = dpm_cost_table_weighted(
-        masks, sxy, jnp.array(dist), jnp.array(w), n=n, overhead=oh,
-        interpret=True, tile=8,
-    )
-    for p, (src, dests) in enumerate(insts):
-        parts = basic_partitions(src, dests, fg)
-        for ci, ids in enumerate(ALL_CANDIDATE_IDS):
-            union = [d for i in ids for d in parts[i]]
-            cc = candidate_cost(fg, src, ids, union)
-            host = (cc.cost_mu + cc.source_leg) if union else 0
-            assert host == float(cw[p, ci]), (p, ids)
-            if union:
-                assert int(rw[p, ci]) == fg.idx(cc.rep)
-
-    # an arbitrary float model (energy) batches too, to f32 rounding
-    cm = get_cost_model("energy")
-    dist_e, w_e, oh_e = route_cost_matrices(fg, cm)
-    ce, re = dpm_cost_table_weighted(
-        masks, sxy, jnp.array(dist_e), jnp.array(w_e), n=n, overhead=oh_e,
-        interpret=True, tile=8,
-    )
-    for p, (src, dests) in enumerate(insts[:5]):
-        parts = basic_partitions(src, dests, fg)
-        for ci, ids in enumerate(ALL_CANDIDATE_IDS):
-            union = [d for i in ids for d in parts[i]]
-            if not union:
-                continue
-            cc = candidate_cost(fg, src, ids, union, cm)
-            assert float(ce[p, ci]) == pytest.approx(
-                cc.cost_mu + cc.source_leg, rel=1e-5
-            )
-
-
-def test_dpm_plan_weighted_matches_int_plan_under_hop_weights():
-    from repro.core import route_cost_matrices
-    from repro.kernels.dpm_cost.ops import dpm_plan_weighted
-
-    n = 8
-    masks, sxy, _ = _instances(n, n, 32, seed=13)
-    dist, w, oh = route_cost_matrices(grid(n))
-    ch0, *_ = dpm_plan(masks, sxy, n=n, interpret=True)
-    chw, cw, rw = dpm_plan_weighted(
-        masks, sxy, jnp.array(dist), jnp.array(w), n=n, overhead=oh,
-        interpret=True,
-    )
-    np.testing.assert_array_equal(np.asarray(ch0), np.asarray(chw))
-
-
-def _host_greedy(costs, reps):
-    """Host-semantics greedy merge over one candidate table (the exact
-    Algorithm 1 loop of dpm_partition: max saving, then fewer partitions,
-    then smallest index; leftover non-empty singles appended)."""
-    nonempty = reps >= 0
-    savings = {}
-    for ci, ids in enumerate(CANDS):
-        if len(ids) == 1 or not nonempty[ci]:
-            continue
-        savings[ci] = max(0.0, sum(costs[i] for i in ids) - costs[ci])
-    chosen = np.zeros(24, bool)
-    covered: set = set()
-    while True:
-        best, best_a = None, 0
-        for ci, a in savings.items():
-            if a <= 0:
-                continue
-            ids = CANDS[ci]
-            if (
-                best is None
-                or a > best_a
-                or (a == best_a and (len(ids), ids) < (len(CANDS[best]),
-                                                       CANDS[best]))
-            ):
-                best, best_a = ci, a
-        if best is None:
-            break
-        chosen[best] = True
-        covered |= set(CANDS[best])
-        for ci in list(savings):
-            if covered & set(CANDS[ci]):
-                savings[ci] = 0
-    for i in range(8):
-        if i not in covered and nonempty[i]:
-            chosen[i] = True
-    return chosen
-
-
-def test_dpm_plan_weighted_float_tie_breaks_match_host_greedy():
-    """Under a float objective (energy) the device merge must reproduce the
-    host loop's exact-tie semantics — near-tied float savings are where a
-    scalar priority encoding would silently pick the wrong candidate."""
-    from repro.core import get_cost_model, route_cost_matrices
-    from repro.kernels.dpm_cost.ops import dpm_plan_weighted
-
-    n = 8
-    masks, sxy, _ = _instances(n, n, 40, seed=17)
-    dist, w, oh = route_cost_matrices(grid(n), get_cost_model("energy"))
-    chw, cw, rw = dpm_plan_weighted(
-        masks, sxy, jnp.array(dist), jnp.array(w), n=n, overhead=oh,
-        interpret=True,
-    )
-    cw, rw, chw = np.asarray(cw), np.asarray(rw), np.asarray(chw)
-    for p in range(cw.shape[0]):
-        np.testing.assert_array_equal(chw[p], _host_greedy(cw[p], rw[p]), p)
 
 
 # ---------------------------------------------------------------------------

@@ -3,8 +3,8 @@
 Covers the full thread: geometry registration, planner coverage on the 26
 3-D wedges and the sparse chiplet link set, weighted heterogeneous links
 changing DPM merge choices, fault detours, host-vs-xsim delivery-set
-equality, telemetry conservation, the generic-topology DPM kernel path, and
-the dist-layer scheduler on 3-D / chiplet fabrics.
+equality, telemetry conservation, and the dist-layer scheduler on 3-D /
+chiplet fabrics.
 """
 import random
 
@@ -301,97 +301,6 @@ def test_telemetry_conservation_on_new_topologies(cfg):
         u, v = link_coords(g, int(lid))
         assert v in g.neighbors(*u)
         assert link_index(g, u, v) == int(lid)
-
-
-# ------------------------------------------------------ generic DPM kernel
-def _mask_instances(g, P, seed):
-    rng = np.random.default_rng(seed)
-    NN = g.num_nodes
-    srcs = [g.from_idx(int(i)) for i in rng.integers(0, NN, P)]
-    masks = np.zeros((P, NN), np.int32)
-    for p in range(P):
-        ds = rng.choice(
-            [i for i in range(NN) if i != g.idx(srcs[p])], size=6,
-            replace=False,
-        )
-        masks[p, ds] = 1
-    return srcs, masks
-
-
-@pytest.mark.parametrize("kind,n,m,params", [
-    ("mesh", 6, None, ()), ("torus", 5, None, ()),
-])
-def test_dpm_plan_topo_matches_2d_kernel(kind, n, m, params):
-    """The generic-topology path must reproduce the closed-form 2-D kernel
-    bit for bit (chosen/costs/reps) when fed the same geometry as tables."""
-    import jax.numpy as jnp
-
-    from repro.kernels.dpm_cost.ops import (
-        dpm_plan,
-        dpm_plan_topo,
-        partition_membership,
-        snake_labels,
-    )
-
-    g = make_topology(kind, n, m, (), params)
-    srcs, masks = _mask_instances(g, 12, seed=3)
-    sxy = np.array([list(s) for s in srcs], np.int32)
-    ch0, c0, r0 = dpm_plan(
-        jnp.asarray(masks), jnp.asarray(sxy), n=n, wrap=(kind == "torus"),
-        interpret=True,
-    )
-    dist, weight, overhead = route_cost_matrices(g)
-    part = np.where(masks > 0, partition_membership(g, srcs), -1)
-    cht, ct, rt = dpm_plan_topo(
-        jnp.asarray(part),
-        jnp.asarray([g.idx(s) for s in srcs], dtype=jnp.int32),
-        jnp.asarray(snake_labels(g)), jnp.asarray(dist),
-        jnp.asarray(weight), np_=8, overhead=float(overhead),
-    )
-    np.testing.assert_array_equal(np.asarray(ch0), np.asarray(cht))
-    np.testing.assert_allclose(np.asarray(c0), np.asarray(ct))
-    np.testing.assert_array_equal(np.asarray(r0), np.asarray(rt))
-
-
-@pytest.mark.parametrize("g", [M333, T333, CP], ids=["mesh3d", "torus3d", "chiplet"])
-def test_dpm_plan_topo_covers_and_matches_host_reps(g):
-    """On the new topologies the kernel's chosen candidates tile the
-    nonempty wedges without overlap, and singles agree with the host's
-    Definition 1 representative and MU cost C_t."""
-    import jax.numpy as jnp
-
-    from repro.core.partition import candidate_cost
-    from repro.kernels.dpm_cost.ops import (
-        dpm_plan_topo,
-        partition_membership,
-        snake_labels,
-    )
-
-    ndim = len(g.from_idx(0))
-    np_ = len(wedge_patterns(ndim))
-    cands = candidate_ids_for(np_)
-    srcs, masks = _mask_instances(g, 8, seed=9)
-    dist, weight, overhead = route_cost_matrices(g, WeightedLinkCost())
-    part = np.where(masks > 0, partition_membership(g, srcs), -1)
-    ch, c, r = dpm_plan_topo(
-        jnp.asarray(part),
-        jnp.asarray([g.idx(s) for s in srcs], dtype=jnp.int32),
-        jnp.asarray(snake_labels(g)), jnp.asarray(dist),
-        jnp.asarray(weight), np_=np_, overhead=float(overhead),
-    )
-    ch, c, r = np.asarray(ch), np.asarray(c), np.asarray(r)
-    for p, src in enumerate(srcs):
-        dests = [g.from_idx(int(i)) for i in np.flatnonzero(masks[p])]
-        parts = basic_partitions(src, dests, g)
-        nonempty = {i for i in range(np_) if parts[i]}
-        covered = [i for ci in np.flatnonzero(ch[p]) for i in cands[ci]]
-        assert sorted(covered) == sorted(set(covered))  # no overlap
-        assert set(covered) >= nonempty  # every nonempty wedge served
-        for i in nonempty:  # singles: host C_t + source leg, host rep
-            cc = candidate_cost(g, src, (i,), parts[i],
-                                cost_model=WeightedLinkCost())
-            assert c[p, i] == pytest.approx(cc.cost_mu + cc.source_leg)
-            assert int(r[p, i]) == g.idx(cc.rep)
 
 
 # ------------------------------------------------------------ dist layer

@@ -1,14 +1,12 @@
 """Topology-layer tests: Torus geometry/routing, planners on the torus,
-the repro.dist.multicast scheduler, and the wrap=True Pallas cost table."""
+and the repro.dist.multicast scheduler."""
 import random
 
-import numpy as np
 import pytest
 
 from repro.core import (
     PLANNERS,
     MeshGrid,
-    candidate_cost,
     grid,
     make_topology,
     plan,
@@ -16,7 +14,7 @@ from repro.core import (
     torus,
     xy_route,
 )
-from repro.core.partition import ALL_CANDIDATE_IDS, basic_partitions
+from repro.core.partition import basic_partitions
 from repro.dist.multicast import (
     Torus,
     dp_broadcast_schedule,
@@ -220,78 +218,6 @@ def test_torus_workload_drains():
     wl = synthetic_workload(cfg, 0.02, 300, seed=2)
     st = simulate(cfg, wl, "DPM")
     assert st.packets_created == st.packets_finished
-
-
-# ---------------------------------------------------------------- kernels
-def _mask_instances(t, P, seed):
-    import jax.numpy as jnp
-
-    rng = random.Random(seed)
-    nodes = _nodes(t)
-    masks, sxy, insts = [], [], []
-    for _ in range(P):
-        k = rng.randint(1, min(14, len(nodes) - 1))
-        picks = rng.sample(nodes, k + 1)
-        src, dests = picks[0], picks[1:]
-        row = np.zeros(t.num_nodes, np.int32)
-        for d in dests:
-            row[t.idx(d)] = 1
-        masks.append(row)
-        sxy.append(src)
-        insts.append((src, dests))
-    return (
-        jnp.array(np.stack(masks)),
-        jnp.array(np.array(sxy, np.int32)),
-        insts,
-    )
-
-
-@pytest.mark.parametrize("dims", [(8, 8), (6, 4), (5, 5)])
-@pytest.mark.parametrize("leg", [True, False])
-def test_dpm_cost_wrap_kernel_vs_ref_and_host(dims, leg):
-    """wrap=True Pallas table == jnp oracle == host planner C_t on the torus."""
-    from repro.kernels.dpm_cost.dpm_cost import dpm_cost_table
-    from repro.kernels.dpm_cost.ref import dpm_cost_table_ref
-
-    n, m = dims
-    t = torus(n, m)
-    masks, sxy, insts = _mask_instances(t, 16, seed=n * 31 + m + leg)
-    ck, rk = dpm_cost_table(
-        masks, sxy, n=n, m=m, wrap=True, include_source_leg=leg,
-        interpret=True, tile=8,
-    )
-    cr, rr = dpm_cost_table_ref(
-        masks, sxy, n=n, m=m, wrap=True, include_source_leg=leg
-    )
-    np.testing.assert_array_equal(np.asarray(ck), np.asarray(cr))
-    np.testing.assert_array_equal(np.asarray(rk), np.asarray(rr))
-    for p, (src, dests) in enumerate(insts):
-        parts = basic_partitions(src, dests, t)
-        for ci, ids in enumerate(ALL_CANDIDATE_IDS):
-            union = [d for i in ids for d in parts[i]]
-            cc = candidate_cost(t, src, ids, union)
-            host = (cc.cost_mu + (cc.source_leg if leg else 0)) if union else 0
-            assert host == int(ck[p, ci]), (dims, leg, p, ids)
-            if union:
-                assert int(rk[p, ci]) == t.idx(cc.rep)
-
-
-def test_dpm_plan_wrap_covers_nonempty_partitions():
-    from repro.kernels.dpm_cost.dpm_cost import CANDS
-    from repro.kernels.dpm_cost.ops import dpm_plan
-
-    t = torus(8)
-    masks, sxy, insts = _mask_instances(t, 32, seed=13)
-    chosen, costs, reps = dpm_plan(masks, sxy, n=8, wrap=True, interpret=True)
-    bits = np.array([sum(1 << i for i in ids) for ids in CANDS])
-    for p, (src, dests) in enumerate(insts):
-        parts = basic_partitions(src, dests, t)
-        nonempty = sum(1 << i for i in range(8) if parts[i])
-        cover = 0
-        for ci in np.where(np.asarray(chosen[p]))[0]:
-            assert cover & bits[ci] & nonempty == 0  # disjoint
-            cover |= bits[ci]
-        assert cover & nonempty == nonempty  # exact cover
 
 
 # ------------------------------------------- conformance (all registered kinds)
