@@ -69,7 +69,6 @@ from .planner import (
     plan,
     plan_dpm,
     plan_dpm_e,
-    segment_plan_for_faults,
 )
 from .routefn import components, provider_for, route_cost_matrices
 from .routing import label_route
@@ -697,26 +696,12 @@ class BatchPlanner:
                     host = [np.asarray(x) for x in out[:4]]
                 t2 = time.perf_counter()
                 with span("repro.planner.decode"):
-                    got = self._decode_chunk(ck, *host, dests, sidx)
-                    plans.extend(self._segment(got) if self._segments else got)
+                    plans.extend(self._decode_chunk(ck, *host, dests, sidx))
                 t3 = time.perf_counter()
                 self._sync_s += t2 - t1
                 self._decode_s += t3 - t2
                 t1 = t3
         return plans
-
-    def _segment(self, plans: list[MulticastPlan]) -> list[MulticastPlan]:
-        """``segment_plan_for_faults`` over one decoded chunk, as ``plan()``
-        segments every plan on a degraded or BFS-routed fabric."""
-        t0 = time.perf_counter()
-        with span("repro.planner.segment"):
-            out = [segment_plan_for_faults(p, self.topo) for p in plans]
-        self._segment_s += time.perf_counter() - t0
-        for p, q in zip(plans, out):
-            if q is not p:
-                self._segmented += 1
-                self._relays += len(q.paths) - len(p.paths)
-        return out
 
     def _decode_chunk(self, keys, chosen, order, reps, modes, dests, sidx):
         """One dispatched chunk's partition tensors -> its
@@ -743,8 +728,10 @@ class BatchPlanner:
           delivers each member it serves at its first visit, as
           ``_deliveries_on`` does.
 
-        Python then touches each plan once, to slice its worms out of the
-        chunk's flat hop and delivery lists (``_decode``)."""
+        On a segmenting fabric the kept worms are then cut at their label
+        reversals (``_segment_worms``). Python then touches each plan once,
+        to slice its worms out of the chunk's flat hop and delivery lists
+        (``_decode``)."""
         B = len(keys)
         t = self._tables()
         NN = self.topo.num_nodes
@@ -869,20 +856,117 @@ class BatchPlanner:
         per_plan = np.bincount(wb[kept], minlength=B)
         local = np.cumsum(keep) - 1 - (np.cumsum(per_plan) - per_plan)[wb]
         parent = np.where(wsub > 0, local[wstart[wg]], -1)[kept]
+        if self._segments:
+            (hops, a, b, dnode, c, d, parent,
+             per_plan) = self._segment_worms(hops, woff, wlen, dpos, dw, keep,
+                                             wb, parent, B)
+        else:
+            a, b = woff[kept], (woff + wlen)[kept]
+            dnode, c, d = hops[dpos], doff[kept], (doff + dcnt)[kept]
         # Python: one slice of the flat hop and delivery lists per worm
         H = self._coords[hops].tolist()
-        D = self._coords[hops[dpos]].tolist()
+        D = self._coords[dnode].tolist()
         worms = [
             PacketPath(H[a:b], D[c:d], None if p < 0 else p)
-            for a, b, c, d, p in zip(
-                woff[kept].tolist(), (woff + wlen)[kept].tolist(),
-                doff[kept].tolist(), (doff + dcnt)[kept].tolist(),
-                parent.tolist())
+            for a, b, c, d, p in zip(a.tolist(), b.tolist(), c.tolist(),
+                                     d.tolist(), parent.tolist())
         ]
         out, lo = [], 0
         for (src, ds), hi in zip(keys, np.cumsum(per_plan).tolist()):
             out.append(self._decode(src, ds, worms[lo:hi]))
             lo = hi
+        return out
+
+    def _segment_worms(self, hops, woff, wlen, dpos, dw, keep, wb, parent,
+                       B):
+        """Cut the chunk's kept worms into label-monotone segments, as
+        ``planner.segment_plan_for_faults`` cuts every plan ``plan()``
+        returns on a degraded or BFS-routed fabric, with array operations
+        over the whole chunk; ``tests/test_batch_planner.py`` pins the two
+        equal, worm for worm:
+
+        * a worm ends a run where the label direction of its next hop
+          reverses (a step to a higher label is up, any other down); the
+          next run starts at that node, so consecutive segments share it;
+        * a delivery goes to the segment with ``s < pos <= e``, a shared
+          node's to the earlier one (a worm never delivers its own first
+          node, so ``pos`` is the first visit past it);
+        * a later segment's parent is the segment before it; a child
+          worm's first segment's parent is the segment of its parent worm
+          that holds the parent's first visit (past its first node) of the
+          child's first node, its partition's representative;
+        * a worm of one node is one segment, its deliveries kept.
+
+        Returns the segments end to end, in the plans' worm order: their
+        hops, start and end in them, delivered nodes with the start and
+        end of each segment's, parents (plan-local, -1 none), and the
+        number of segments of each plan."""
+        t0 = time.perf_counter()
+        with span("repro.planner.segment"):
+            kept = np.nonzero(keep)[0]
+            K = len(kept)
+            wk = wb[kept]
+            kl = wlen[kept]
+            ko = np.cumsum(kl) - kl
+            n = int(kl.sum())
+            shift = np.zeros(len(wlen), np.int64)
+            shift[kept] = woff[kept] - ko
+            hops = hops[np.repeat(shift[kept], kl) + np.arange(n)]
+            lab = self._tables().labels[hops]
+            head = np.zeros(n, bool)
+            head[ko] = True
+            cut = np.zeros(n, bool)
+            up = lab[1:] > lab[:-1]
+            cut[1:-1] = up[1:] != up[:-1]
+            cut &= ~head
+            cut[ko + kl - 1] = False
+            # a cut node ends one segment and starts the next: laid out
+            # twice, the second copy a segment's first node
+            twice = 1 + cut
+            at = np.cumsum(twice) - twice
+            starts = np.zeros(n + int(cut.sum()), bool)
+            starts[at[head]] = True
+            starts[at[cut] + 1] = True
+            # each hop's segment, a cut node's the earlier of its two
+            seg_of = (np.cumsum(starts) - 1)[at]
+            sstart = np.nonzero(starts)[0]
+            S = len(sstart)
+            fs = seg_of[ko]
+            nseg = seg_of[ko + kl - 1] - fs + 1
+            splan = np.repeat(wk, nseg)
+            per_plan = np.bincount(splan, minlength=B)
+            # parents: the segment before, or for a child worm's first
+            # segment the parent worm's at its first visit of the child's
+            # first node
+            sparent = np.arange(S) - 1
+            child = np.nonzero(parent >= 0)[0]
+            nk = np.bincount(wk, minlength=B)
+            pk = (np.cumsum(nk) - nk)[wk[child]] + parent[child]
+            tgt = np.full(K, -1, np.int64)
+            tgt[pk] = hops[ko[child]]
+            hit = np.nonzero((hops == np.repeat(tgt, kl)) & ~head)[0]
+            hk = np.searchsorted(ko, hit, side="right") - 1
+            new = np.ones(len(hit), bool)
+            new[1:] = hk[1:] != hk[:-1]
+            visit = np.zeros(K, np.int64)
+            visit[hk[new]] = hit[new]
+            sparent[fs] = -1
+            sparent[fs[child]] = seg_of[visit[pk]]
+            sparent = np.where(
+                sparent >= 0,
+                sparent - (np.cumsum(per_plan) - per_plan)[splan], -1)
+            # deliveries of the kept worms, by segment
+            dk = keep[dw]
+            dp = dpos[dk] - shift[dw[dk]]
+            dcnt = np.bincount(seg_of[dp], minlength=S)
+            doff = np.cumsum(dcnt) - dcnt
+            self._relays += S - K
+            self._segmented += int(np.count_nonzero(
+                np.bincount(wk, nseg - 1, minlength=B)))
+            out = (np.repeat(hops, twice), sstart,
+                   np.append(sstart[1:], len(starts)), hops[dp], doff,
+                   doff + dcnt, sparent, per_plan)
+        self._segment_s += time.perf_counter() - t0
         return out
 
     def _chain_visits(self, chain, node, high, rep) -> np.ndarray:

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import random
 
+import numpy as np
 import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
@@ -37,6 +38,7 @@ from repro.core import (
     planner_for,
     registered_topology_kinds,
     router_failure,
+    segment_plan_for_faults,
     torus,
     torus3d,
 )
@@ -734,6 +736,177 @@ def test_degraded_chain_matrices_match_label_walks(n, faults):
             passed_some |= bool(beyond)
     assert passed_some
     assert label_chain_passes(grid(n)) is None
+
+
+# ---------------------------------------------------------------------------
+# Segmenting inside the chunk decode, against segment_plan_for_faults
+# ---------------------------------------------------------------------------
+def _distinct_live_requests(g, dead, count, seed):
+    """``count`` distinct instances of fanout 1 to 16 over the live nodes."""
+    rng = random.Random(seed)
+    nodes = [u for u in g.nodes() if u not in dead]
+    seen: set = set()
+    while len(seen) < count:
+        src = rng.choice(nodes)
+        k = rng.randint(1, min(16, len(nodes) - 1))
+        seen.add((src, tuple(sorted(rng.sample(
+            [u for u in nodes if u != src], k)))))
+    return [(src, list(ds)) for src, ds in sorted(seen)]
+
+
+def _segment_oracle(g, reqs):
+    """The chunk decode left unsegmented, then ``segment_plan_for_faults``
+    over each plan: ``(unsegmented, segmented)`` plan lists."""
+    raw = BatchPlanner(g, "DPM")
+    raw._segments = False
+    unseg = raw.plan_many(reqs)
+    return unseg, [segment_plan_for_faults(p, g) for p in unseg]
+
+
+@pytest.mark.parametrize("chunk", [1, 7, bpm.DISPATCH_CHUNK])
+@pytest.mark.parametrize("n,faults", DEGRADED,
+                         ids=[f"{n}x{n}-{f}" for n, f in DEGRADED])
+def test_array_segmentation_equals_segment_plan_for_faults(n, faults, chunk):
+    """One dispatched chunk of 1, 7 or 512 plans on every degraded fabric:
+    the decode's array segmentation gives, worm for worm (hops,
+    deliveries, parents), what ``segment_plan_for_faults`` makes of the
+    unsegmented array decode, and its counters count the plans it cut and
+    the worms it added."""
+    g, dead = _degraded(n, faults)
+    reqs = _distinct_live_requests(g, dead, chunk, seed=7 * n + chunk)
+    unseg, want = _segment_oracle(g, reqs)
+    bp = BatchPlanner(g, "DPM")
+    got = bp.plan_many(reqs)
+    for pg, pw in zip(got, want):
+        assert pg.paths == pw.paths
+    info = bp.info()
+    assert info.dispatches == 1 and info.batched_plans == chunk
+    assert info.segmented_plans == sum(
+        pw is not pu for pu, pw in zip(unseg, want))
+    assert info.relay_worms == sum(
+        len(pw.paths) - len(pu.paths) for pu, pw in zip(unseg, want))
+    assert 0.0 < info.segment_s <= info.decode_s
+
+
+def test_plan_without_reversal_is_left_as_it_is():
+    """A plan none of whose worms reverses its label direction comes out
+    of the segmenting decode unchanged, and is not counted."""
+    g, dead = _degraded(8, 12)
+    reqs = _distinct_live_requests(g, dead, 64, seed=81)
+    unseg, want = _segment_oracle(g, reqs)
+    (src, dests), p = next((r, pu) for r, pu, pw in zip(reqs, unseg, want)
+                           if pw is pu and len(pu.paths) > 1)
+    bp = BatchPlanner(g, "DPM")
+    assert bp.plan_one(src, dests) == p == plan("DPM", g, src, dests)
+    info = bp.info()
+    assert info.segmented_plans == info.relay_worms == 0
+    assert info.segment_s > 0.0
+
+
+def test_child_whose_start_its_parent_visits_twice():
+    """A child worm whose first node its parent worm visits more than once
+    hangs off the parent's segment at the first of those visits."""
+    g, dead = _degraded(6, 20)
+    reqs = _distinct_live_requests(g, dead, 256, seed=620)
+    unseg, _ = _segment_oracle(g, reqs)
+
+    def revisits(p):
+        return any(w.parent is not None
+                   and p.paths[w.parent].hops[1:].count(w.hops[0]) > 1
+                   for w in p.paths)
+
+    picked = [r for r, pu in zip(reqs, unseg) if revisits(pu)]
+    assert picked
+    for (src, dests), pb in zip(picked, BatchPlanner(g, "DPM").plan_many(
+            picked)):
+        assert pb == plan("DPM", g, src, dests)
+
+
+def _flat_worms(g, plans, dropped):
+    """The chunk arrays ``BatchPlanner._segment_worms`` reads for these
+    plans' worms, with ``dropped[b]`` (a worm or None) laid out after
+    plan ``b``'s worms and not kept."""
+    hops, woff, wlen, dpos, dw, keep, wb, parent = ([] for _ in range(8))
+    for b, p in enumerate(plans):
+        worms = [(w, True) for w in p.paths]
+        if dropped[b] is not None:
+            worms.append((dropped[b], False))
+        for w, kept in worms:
+            for d in w.deliveries:
+                dpos.append(len(hops) + w.hops.index(d))
+                dw.append(len(wlen))
+            woff.append(len(hops))
+            wlen.append(len(w.hops))
+            hops += [g.idx(h) for h in w.hops]
+            keep.append(kept)
+            wb.append(b)
+            if kept:
+                parent.append(-1 if w.parent is None else w.parent)
+    return [np.array(x, bool if x is keep else np.int64)
+            for x in (hops, woff, wlen, dpos, dw, keep, wb, parent)]
+
+
+def _walk(g, rng, start, steps):
+    hops = [start]
+    for _ in range(steps):
+        hops.append(rng.choice(g.neighbors(*hops[-1])))
+    return hops
+
+
+def _serves(rng, hops):
+    """Some of the nodes a worm visits past its first node, in first-visit
+    order; a worm of one node serves its node."""
+    if len(hops) == 1:
+        return list(hops)
+    firsts = list(dict.fromkeys(h for h in hops[1:] if h != hops[0]))
+    return [h for h in firsts if rng.random() < 0.5]
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_segment_worms_matches_segment_plan_for_faults(seed):
+    """``_segment_worms`` on hand-made chunks: random walks as worms,
+    single-node worms (a root and children), children of a worm that
+    visits their first node several times, and dropped worms that must
+    not count, against ``segment_plan_for_faults`` plan by plan."""
+    from repro.core.planner import MulticastPlan, PacketPath
+
+    g, _ = _degraded(8, 12)
+    rng = random.Random(seed)
+    plans, dropped = [], []
+    for _ in range(40):
+        paths = []
+        for _ in range(rng.randint(1, 4)):
+            hops = _walk(g, rng, rng.choice(g.nodes()), rng.randint(0, 12))
+            paths.append(PacketPath(hops, _serves(rng, hops)))
+            if len(hops) > 1:
+                at = rng.choice(hops[1:])  # the children's first node
+                parent = len(paths) - 1
+                for _ in range(rng.randint(0, 3)):
+                    kid = _walk(g, rng, at, rng.choice([0, 1, 5, 9]))
+                    paths.append(PacketPath(kid, _serves(rng, kid), parent))
+        plans.append(MulticastPlan("DPM", paths[0].hops[0], [], paths))
+        walk = _walk(g, rng, rng.choice(g.nodes()), 8)
+        dropped.append(rng.choice([None, PacketPath(walk, _serves(rng, walk))]))
+    want = [segment_plan_for_faults(p, g) for p in plans]
+    assert any(len(w.hops) == 1 and w.parent is not None
+               for p in plans for w in p.paths)
+    bp = BatchPlanner(g, "DPM")
+    hops, a, b, dnode, c, d, par, per_plan = bp._segment_worms(
+        *_flat_worms(g, plans, dropped), len(plans))
+    node = g.from_idx
+    got = [PacketPath([node(int(h)) for h in hops[i:j]],
+                      [node(int(x)) for x in dnode[k:m]],
+                      None if q < 0 else int(q))
+           for i, j, k, m, q in zip(a, b, c, d, par)]
+    lo = 0
+    for p, hi in zip(want, np.cumsum(per_plan)):
+        assert got[lo:hi] == p.paths
+        lo = hi
+    info = bp.info()
+    assert info.segmented_plans == sum(
+        q is not p for p, q in zip(plans, want))
+    assert info.relay_worms == sum(
+        len(q.paths) - len(p.paths) for p, q in zip(plans, want))
 
 
 # ---------------------------------------------------------------------------
